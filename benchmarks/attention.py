@@ -29,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS
-from repro.kernels.attention import INTERPRET
 from repro.models import LM
 from repro.models.layers import attention, paged_attention
 from repro.models.transformer import POS_SENTINEL
@@ -71,8 +70,8 @@ def bench_paged_decode(lens, ps, Hkv, G, D, window):
     B = len(lens)
     nb = -(-max(lens) // ps) + 1
     P = 1 + sum(-(-s // ps) for s in lens)
-    k = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(P, Hkv, ps, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(P, Hkv, ps, D)), jnp.float32)
     pos = np.full((P, ps), POS_SENTINEL, np.int32)
     bt = np.zeros((B, nb), np.int32)
     nxt = 1
@@ -138,7 +137,7 @@ def main():
         t_ref, t_pal = bench_paged_decode(
             [390, 51, 222, 117, 303, 64, 480, 12], 16, 2, 2, 64, window=128)
         tps_r, tps_p = bench_engine(n_new=32, max_len=128)
-    if INTERPRET:
+    if jax.default_backend() != "tpu":
         print("NOTE: off-TPU run -- kernels in interpret mode; timings are "
               "emulation, only the parity gates are meaningful here.")
     else:

@@ -69,7 +69,8 @@ def main() -> None:
     for name, nbytes in pw.bucket_nbytes().items():
         print(f"    bucket {name:<6}: {nbytes:>12,} B")
 
-    mode = "interpret (CPU)" if ops.INTERPRET else "compiled (TPU)"
+    mode = ("compiled (TPU)" if jax.default_backend() == "tpu"
+            else "interpret (CPU)")
     t_i8 = _time(lambda: ops.quant_matmul(x, qi, si.reshape(-1)), args.iters)
     t_pk = _time(lambda: ops.packed_mixed_matmul(x, pw), args.iters)
     print(f"wall-clock [{mode}]  int8 matmul  : {t_i8 * 1e3:8.2f} ms")

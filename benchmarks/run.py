@@ -8,6 +8,7 @@ from __future__ import annotations
 
 import json
 import pathlib
+import sys
 import time
 
 import jax
@@ -193,9 +194,12 @@ BENCHES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
+    """Print one CSV row per bench; a bench that raises still gets its
+    ``nan,ERROR`` row, and the run then exits non-zero."""
     print("name,us_per_call,derived")
     ctx = None
+    failed = []
     for name, fn, needs_sub in BENCHES:
         if needs_sub and ctx is None:
             ctx = _substrate()
@@ -204,7 +208,11 @@ def main() -> None:
             print(f"{name},{us:.1f},{derived}", flush=True)
         except Exception as e:                      # pragma: no cover
             print(f"{name},nan,ERROR:{e!r}", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"failed benches: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

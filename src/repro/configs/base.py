@@ -15,7 +15,8 @@ def dense(kind: str = "attn", moe: bool = False, ffn: bool = True) -> BlockDef:
 class ArchSpec:
     """One assigned architecture: production config + reduced smoke config."""
     arch_id: str
-    config: "LMConfig"               # full production dims (dry-run only)
+    config: "LMConfig"               # published widths (dry-run; chip_smoke
+                                     # serves gemma2-2b's on one chip)
     smoke: "LMConfig"                # tiny same-family config (CPU tests)
     family: str                      # dense | moe | hybrid | ssm | audio | vlm
     skip_shapes: Tuple[str, ...] = ()

@@ -18,8 +18,8 @@ pack.py holds the bit-packing format + the PackedWeight pytree container
 (see docs/packed_layout.md); ops.py exposes the jit'd public wrappers
 (padding + pallas/ref dispatch); ref.py holds the pure-jnp oracles every
 kernel is allclose-tested against (for attention the oracle is
-models/layers.attention_ref).  Kernels validate under interpret=True on
-CPU; TPU is the compile target.
+models/layers.attention_ref).  Kernels run interpreted on the CPU
+backend and compiled on TPU (backend.py decides when a kernel is traced).
 """
 from repro.kernels.attention import (flash_attention, paged_decode_attention,
                                      paged_prefill_attention)

@@ -2,16 +2,17 @@
 
 Two kernels cover the serving hot path (models/layers.py owns the
 ``impl="pallas"|"ref"`` dispatch; the jnp chunked-flash path there is the
-bit-accuracy oracle both kernels are property-tested against):
+oracle both kernels are property-tested against):
 
 * :func:`flash_attention` -- tiled flash-attention forward for prefill (and
-  dense-cache decode, ``Sq == 1``).  Grid ``(B, Hkv, nq, nk)`` with the KV
-  axis innermost: the f32 accumulator, running max ``m`` and normalizer ``l``
+  dense-cache decode, ``Sq == 1``).  Grid ``(B, nq, nk)`` with the KV axis
+  innermost: the f32 accumulator, running max ``m`` and normalizer ``l``
   live in VMEM scratch across the KV tiles of one q tile (online softmax),
   so no (Sq, Skv) score matrix ever exists.  GQA is folded into the tile:
-  one program handles all ``G = Hq/Hkv`` query heads that share a KV head,
-  loading each K/V tile once per KV head instead of once per query head.
-  Causal, sliding-window and softcap masking run on the score tile in VMEM.
+  each KV head's ``G = Hq/Hkv`` query heads are rows of one ``(bq*G, D)``
+  matrix, so each K/V tile is loaded once per KV head, not once per query
+  head.  Causal, sliding-window and softcap masking run on the score tile
+  in VMEM.
 
 * :func:`paged_prefill_attention` -- block-table-aware attention over the
   paged KV pool (serve/paged_kv.py layout) for q tiles of ``k`` tokens per
@@ -30,25 +31,36 @@ bit-accuracy oracle both kernels are property-tested against):
   the whole tile (Pallas skips the re-fetch when consecutive steps map to
   the same block, so the clip costs no extra HBM traffic).
 
-int8 KV pages (``kv_bits=8`` pool): when the pool stores int8, the kernel
-streams the packed page plus its per-(slot, head) scale page into VMEM and
-dequantizes there -- KV HBM traffic stays 1 byte/element; f32 only ever
+Block layout (what the TPU compiler accepts: the last two dims of every
+block are whole array dims or multiples of (8, 128)).  Both kernels run
+head-major: the wrappers fold q to ``(B, Hkv, rows, D)`` with ``rows =
+tokens*G`` (row ``t*G + g`` is query head ``h*G + g`` of token ``t``) and
+expand q positions to one ``(rows, 1)`` column per row, so no kernel
+reshapes or repeats anything.  A block carries every KV head -- q ``(1, Hkv,
+rows, D)``, a KV tile ``(1, Hkv, bk, D)``, a pool page ``(1, Hkv, ps, D)``
+of the head-major pool ``(P, Hkv, ps, D)`` -- and the kernel loops the heads
+with static indices.  KV positions arrive as ``(1, bk)`` rows (``(B, 1,
+Skv)`` / ``(P, 1, ps)`` arrays).  The layout is the same under the CPU
+interpreter and on TPU: interpret-mode tests trace what the chip compiles.
+
+int8 KV pages (``kv_bits=8`` pool): the kernel streams the int8 page plus
+its per-(head, slot) scale page ``(1, Hkv, ps)`` into VMEM and applies the
+scales on-chip -- to the scores for K (``q.(kq*s) = (q.kq)*s``) and to the
+probabilities for V (``p.(vq*s) = (p*s).vq``), where a slot's scale is a
+row broadcast -- so KV HBM traffic stays 1 byte/element and f32 only ever
 exists on-chip.
 
 Numerics shared by both kernels (matching the jnp oracle step for step):
 scores, softmax statistics and accumulation are f32 regardless of input
 dtype; masked slots contribute exact zeros (``exp(-inf - m_safe) == 0``);
 position ``POS_SENTINEL`` (int32 max) is unconditionally unattendable; an
-all-masked row normalizes by ``max(l, 1e-30)`` to exact zeros.  With one KV
-tile the update degenerates to the oracle's single-shot softmax (``alpha``
-is exactly 0 on the first tile, exactly 1 on tiles that do not move the
-running max), so small shapes reproduce the reference bit for bit; multiple
-tiles differ only by documented f32 rescale rounding (~1e-7).
+all-masked row normalizes by ``max(l, 1e-30)`` to exact zeros.  Results
+match the oracle to f32 rounding: the online-softmax rescale, matmul
+accumulation order and (int8) scale placement each differ from the
+oracle's single-shot softmax by a few ulps.
 
-Kernels validate under ``interpret=True`` on CPU (the test path); TPU is the
-compile target.  Off-TPU the wrappers skip lane padding so the contraction
-lengths -- and therefore the f32 rounding -- match the oracle exactly; on
-TPU they pad the head dim to the 128-lane boundary (zero columns are exact).
+Where a kernel runs is decided at trace time (kernels/backend.py):
+interpreted on the CPU backend, compiled on TPU.
 """
 from __future__ import annotations
 
@@ -61,12 +73,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not _ON_TPU
+from repro.kernels.backend import interpret_mode
 
 NEG_INF = float("-inf")
 POS_SENTINEL = np.iinfo(np.int32).max
 _LANES = 128                 # TPU vector lane count (last-dim tile unit)
+_SUBLANES = 8                # f32 sublane count (second-minor tile unit)
 
 
 def _pad_axis(x, mult, axis, value=0):
@@ -78,8 +90,30 @@ def _pad_axis(x, mult, axis, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def _mask_tile(s, qp, kp, *, causal, window):
-    """Mask a (rows, bk) score tile.  qp (rows, 1) / kp (1, bk) int32.
+def _fold_heads(x, Hkv):
+    """(B, S, Hkv*G, D) -> (B, Hkv, S*G, D): row ``s*G + g`` of head h."""
+    B, S, Hq, D = x.shape
+    G = Hq // Hkv
+    return x.reshape(B, S, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(
+        B, Hkv, S * G, D)
+
+
+def _unfold_heads(x, S, G):
+    """Inverse of :func:`_fold_heads` for the first ``S*G`` rows."""
+    B, Hkv, _, D = x.shape
+    return x[:, :, :S * G].reshape(B, Hkv, S, G, D).transpose(
+        0, 2, 1, 3, 4).reshape(B, S, Hkv * G, D)
+
+
+def _row_positions(q_pos, G, rows):
+    """(B, S) token positions -> (B, rows, 1) per-row column, sentinel
+    padded (padded rows are sliced off by the wrappers)."""
+    qp = jnp.repeat(q_pos.astype(jnp.int32), G, axis=1)
+    return _pad_axis(qp, rows, 1, value=POS_SENTINEL)[..., None]
+
+
+def _tile_mask(qp, kp, *, causal, window):
+    """Attendability of a (rows, bk) score tile.  qp (rows, 1) / kp (1, bk).
 
     The sentinel test makes padded / scrubbed / trash slots unattendable even
     for idle decode lanes whose own q_pos is the sentinel (the oracle leaves
@@ -90,155 +124,160 @@ def _mask_tile(s, qp, kp, *, causal, window):
         mask &= kp <= qp
     if window is not None:
         mask &= kp > qp - window
-    return jnp.where(mask, s, NEG_INF)
+    return mask
 
 
-def _online_update(s, vt, acc_ref, m_ref, l_ref):
-    """One online-softmax accumulation step over a masked score tile.
+def _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref, *,
+                  scale, cap, k_scale=None, v_scale=None):
+    """One online-softmax accumulation step for every KV head of a tile.
 
-    Mirrors the oracle's scan body exactly: on the first tile ``alpha`` is 0
-    and the update reduces to single-shot softmax; on tiles that leave the
-    running max unchanged ``alpha == exp(0) == 1`` and the accumulate is
-    exact.  ``m``/``l`` are lane-replicated (rows, _LANES) VMEM scratch.
+    q_ref (1, Hkv, rows, D); k_ref / v_ref (1, Hkv, bk, D); mask (rows, bk)
+    bool, shared by all heads; scratch acc (Hkv, rows, D) and lane-replicated
+    m / l (Hkv, rows, _LANES).  ``k_scale`` / ``v_scale`` (Hkv, bk): int8
+    page scales.  Mirrors the oracle's scan body: on the first tile
+    ``alpha`` is 0 and the update reduces to single-shot softmax; on tiles
+    that leave the running max unchanged ``alpha == exp(0) == 1``.
     """
-    m_prev = m_ref[:, :1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe)
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    pv = jax.lax.dot_general(p, vt, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(
-        l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32) * scale
+        s = jax.lax.dot_general(q, k_ref[0, h].astype(jnp.float32),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if k_scale is not None:
+            s = s * k_scale[h:h + 1]
+        if cap is not None:
+            s = cap * jnp.tanh(s / cap)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[h][:, :1]
+        l_prev = l_ref[h][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
+        pv_in = p if v_scale is None else p * v_scale[h:h + 1]
+        pv = jax.lax.dot_general(pv_in, v_ref[0, h].astype(jnp.float32),
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[h] = acc_ref[h] * alpha + pv
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(
+            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+            l_ref.shape[1:])
 
 
-def _finalize(acc_ref, l_ref, shape, dtype):
-    o = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-    return o.reshape(shape).astype(dtype)
+def _init_scratch(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _finalize(o_ref, acc_ref, l_ref):
+    for h in range(o_ref.shape[1]):
+        o = acc_ref[h] / jnp.maximum(l_ref[h][:, :1], 1e-30)
+        o_ref[0, h] = o.astype(o_ref.dtype)
+
+
+def _scratch(Hkv, rows, D):
+    return [pltpu.VMEM((Hkv, rows, D), jnp.float32),
+            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32),
+            pltpu.VMEM((Hkv, rows, _LANES), jnp.float32)]
 
 
 # ------------------------------------------------------------ flash prefill
 def _flash_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, nk, causal, window, cap, scale,
-                  G):
-    j = pl.program_id(3)
+                  acc_ref, m_ref, l_ref, *, nk, causal, window, cap, scale):
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    bq, D = q_ref.shape[1], q_ref.shape[3]
-    qt = (q_ref[0].astype(jnp.float32) * scale).reshape(bq * G, D)
-    kt = k_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bq*G, bk)
-    if cap is not None:
-        s = cap * jnp.tanh(s / cap)
-    qp = jnp.repeat(qp_ref[0, :], G)[:, None]
-    s = _mask_tile(s, qp, kp_ref[0, :][None, :], causal=causal, window=window)
-    _online_update(s, v_ref[0, :, 0, :].astype(jnp.float32),
-                   acc_ref, m_ref, l_ref)
+    mask = _tile_mask(qp_ref[0], kp_ref[0], causal=causal, window=window)
+    _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
+                  scale=scale, cap=cap)
 
     @pl.when(j == nk - 1)
     def _done():
-        o_ref[0] = _finalize(acc_ref, l_ref, (bq, G, D), o_ref.dtype)
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "attn_cap",
                                              "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
-                    attn_cap=None, bq=128, bk=128, interpret=INTERPRET):
+                    attn_cap=None, bq=128, bk=128, interpret=None):
     """Tiled flash-attention forward (prefill / dense-cache decode).
 
     q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D); q_pos: (B, Sq) int32;
     kv_pos: (B, Skv) int32.  Returns (B, Sq, Hq, D) in q.dtype.  Pure
     function of positions: causal / sliding-window validity comes from
     comparing q_pos against kv_pos, so ring-buffer (rolled) caches and
-    padded tails (position == sentinel) need no extra arguments.
+    padded tails (position == sentinel) need no extra arguments.  On TPU a
+    KV tile shorter than the (padded) sequence must be a multiple of 128
+    (the lane width of its position row); the defaults satisfy this.
     """
+    interpret = interpret_mode(interpret)
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     scale = 1.0 / math.sqrt(D)
-    bq = min(bq, -(-Sq // 8) * 8)
-    bk = min(bk, -(-Skv // 8) * 8)
-    q_ = _pad_axis(q, bq, 1)
-    k_ = _pad_axis(k, bk, 1)
-    v_ = _pad_axis(v, bk, 1)
-    # padded q rows mask everything (causal qp=0 / sentinel kp) -> sliced off;
-    # padded kv slots carry the sentinel position -> never attended
-    qp_ = _pad_axis(q_pos.astype(jnp.int32), bq, 1)
-    kp_ = _pad_axis(kv_pos.astype(jnp.int32), bk, 1, value=POS_SENTINEL)
-    if not interpret:            # TPU lane alignment; zero columns are exact
-        q_, k_, v_ = (_pad_axis(x, _LANES, 3) for x in (q_, k_, v_))
-    Dp = q_.shape[3]
-    nq, nk = q_.shape[1] // bq, k_.shape[1] // bk
+    bq = min(bq, -(-Sq // _SUBLANES) * _SUBLANES)
+    bk = min(bk, -(-Skv // _SUBLANES) * _SUBLANES)
+    Sq_p = -(-Sq // bq) * bq
+    # padded q rows carry the sentinel position and are sliced off; padded
+    # kv slots carry the sentinel position too -> never attended
+    q_ = _fold_heads(_pad_axis(q, bq, 1), Hkv)
+    qp_ = _row_positions(q_pos, G, Sq_p * G)
+    k_ = _pad_axis(k, bk, 1).transpose(0, 2, 1, 3)          # (B, Hkv, S, D)
+    v_ = _pad_axis(v, bk, 1).transpose(0, 2, 1, 3)
+    kp_ = _pad_axis(kv_pos.astype(jnp.int32), bk, 1,
+                    value=POS_SENTINEL)[:, None, :]         # (B, 1, Skv)
+    nq, nk = Sq_p // bq, k_.shape[2] // bk
+    rq = bq * G
     out = pl.pallas_call(
         functools.partial(_flash_kernel, nk=nk, causal=causal, window=window,
-                          cap=attn_cap, scale=scale, G=G),
-        grid=(B, Hkv, nq, nk),
+                          cap=attn_cap, scale=scale),
+        grid=(B, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, G, Dp), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dp), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dp), lambda b, h, i, j: (b, j, h, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, j: (b, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (b, j)),
+            pl.BlockSpec((1, Hkv, rq, D), lambda b, i, j: (b, 0, i, 0)),
+            pl.BlockSpec((1, Hkv, bk, D), lambda b, i, j: (b, 0, j, 0)),
+            pl.BlockSpec((1, Hkv, bk, D), lambda b, i, j: (b, 0, j, 0)),
+            pl.BlockSpec((1, rq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bq, G, Dp),
-                               lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, q_.shape[1], Hq, Dp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq * G, Dp), jnp.float32),
-            pltpu.VMEM((bq * G, _LANES), jnp.float32),
-            pltpu.VMEM((bq * G, _LANES), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, rq, D), lambda b, i, j: (b, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
+        scratch_shapes=_scratch(Hkv, rq, D),
         interpret=interpret,
     )(q_, k_, v_, qp_, kp_)
-    return out[:, :Sq, :, :D]
+    return _unfold_heads(out, Sq, G)
 
 
 # --------------------------------------------- paged prefill / decode
 def _paged_kernel(bt_ref, first_ref, q_ref, qp_ref, k_ref, v_ref, pos_ref,
-                  *rest, nb, window, cap, scale, G, quant):
+                  *rest, nb, window, cap, scale, quant):
     if quant:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        k_scale, v_scale = ks_ref[0], vs_ref[0]              # (Hkv, ps)
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
+        k_scale = v_scale = None
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    bq, D = q_ref.shape[1], q_ref.shape[3]
-    qt = (q_ref[0].astype(jnp.float32) * scale).reshape(bq * G, D)
-    kt = k_ref[0, :, 0, :].astype(jnp.float32)            # (ps, D)
-    vt = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quant:                  # int8 pages: dequantize in VMEM, not in HBM
-        kt = kt * ks_ref[0, :, 0][:, None]
-        vt = vt * vs_ref[0, :, 0][:, None]
-    s = jax.lax.dot_general(qt, kt, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bq*G, ps)
-    if cap is not None:
-        s = cap * jnp.tanh(s / cap)
-    qp = jnp.repeat(qp_ref[0, :], G)[:, None]
-    s = _mask_tile(s, qp, pos_ref[0][None, :], causal=True, window=window)
+    mask = _tile_mask(qp_ref[0], pos_ref[0], causal=True, window=window)
     # walk steps past the last logical block were clipped onto block nb-1 by
     # the index_map: mask the duplicate tile entirely
-    s = jnp.where(first_ref[b] + j < nb, s, NEG_INF)
-    _online_update(s, vt, acc_ref, m_ref, l_ref)
+    mask &= first_ref[b] + j < nb
+    _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
+                  scale=scale, cap=cap, k_scale=k_scale, v_scale=v_scale)
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _done():
-        o_ref[0] = _finalize(acc_ref, l_ref, (bq, G, D), o_ref.dtype)
+        _finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "attn_cap",
@@ -246,7 +285,7 @@ def _paged_kernel(bt_ref, first_ref, q_ref, qp_ref, k_ref, v_ref, pos_ref,
 def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
                             q_pos, window=None, attn_cap=None,
                             k_scale_pages=None, v_scale_pages=None,
-                            interpret=INTERPRET):
+                            interpret=None):
     """Causal attention over the paged KV pool for q-tiles of k tokens.
 
     The block-table page walk generalized from single-token decode to the
@@ -254,28 +293,31 @@ def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
     prompt chunk, a lone decode token, or sentinel padding) that all read KV
     through the same scalar-prefetched block-table row.
 
-    q: (B, k, Hq, D); ``*_pages``: (P, page_size, Hkv, D) physical pool
-    (``pos_pages`` (P, page_size) int32); block_tables: (B, nb) int32;
-    q_pos: (B, k) int32 per-row token positions, **left-aligned**: real
-    tokens occupy columns ``0..c-1`` in ascending position order and padded
-    columns carry ``POS_SENTINEL``.  int8 pools pass ``k_scale_pages`` /
-    ``v_scale_pages`` (P, page_size, Hkv) f32 and the kernel dequantizes in
-    VMEM.  Returns (B, k, Hq, D) in q.dtype.
+    q: (B, k, Hq, D); ``k_pages`` / ``v_pages``: (P, Hkv, page_size, D)
+    head-major physical pool; ``pos_pages`` (P, page_size) int32;
+    block_tables: (B, nb) int32; q_pos: (B, k) int32 per-row token
+    positions, **left-aligned**: real tokens occupy columns ``0..c-1`` in
+    ascending position order and padded columns carry ``POS_SENTINEL``.
+    int8 pools pass ``k_scale_pages`` / ``v_scale_pages`` (P, Hkv,
+    page_size) f32 and the kernel applies them in VMEM.  Returns (B, k, Hq,
+    D) in q.dtype.
 
-    Grid (B, Hkv, nb): step ``j`` of sequence ``b`` DMAs physical page
-    ``bt[b, min(first[b]+j, nb-1)]`` (index_map over the scalar-prefetched
-    table).  ``first`` -- computed from the row's *lowest* real position
-    (column 0, thanks to left-alignment) -- skips the logical blocks wholly
-    below the sliding window, so out-of-window pages never leave HBM;
-    not-yet-grown tail blocks point at the trash page whose slots are
-    all-sentinel.  Causal masking against each row's own position handles
-    chunk offsets: a chunk token attends earlier chunks' pages plus its own
-    chunk's already-written slots, never its future.  Fully padded rows
-    (q_pos all sentinel under a window; all-trash tables otherwise) produce
-    zeros or garbage the scheduler ignores.
+    Grid (B, nb): step ``j`` of sequence ``b`` DMAs physical page
+    ``bt[b, min(first[b]+j, nb-1)]`` -- every KV head of it -- through an
+    index_map over the scalar-prefetched table.  ``first`` -- computed from
+    the row's *lowest* real position (column 0, thanks to left-alignment)
+    -- skips the logical blocks wholly below the sliding window, so
+    out-of-window pages never leave HBM; not-yet-grown tail blocks point at
+    the trash page whose slots are all-sentinel.  Causal masking against
+    each row's own position handles chunk offsets: a chunk token attends
+    earlier chunks' pages plus its own chunk's already-written slots, never
+    its future.  Fully padded rows (q_pos all sentinel under a window;
+    all-trash tables otherwise) produce zeros or garbage the scheduler
+    ignores.
     """
+    interpret = interpret_mode(interpret)
     B, k, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     nb = block_tables.shape[1]
     G = Hq // Hkv
     quant = k_pages.dtype == jnp.int8
@@ -290,74 +332,53 @@ def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
         first = jnp.clip((qp[:, 0] - (window - 1)) // ps, 0, nb - 1)
     else:
         first = jnp.zeros((B,), jnp.int32)
+    rows = -(-(k * G) // _SUBLANES) * _SUBLANES
+    q_ = _pad_axis(_fold_heads(q, Hkv), _SUBLANES, 2)
+    qp_ = _row_positions(qp, G, rows)
 
-    q_, k_, v_ = q, k_pages, v_pages
-    qp_, pos_ = qp, pos_pages
-    if not interpret:            # TPU alignment: slot sublanes + head lanes
-        q_ = _pad_axis(q_, 8, 1)
-        qp_ = _pad_axis(qp_, 8, 1, value=POS_SENTINEL)
-        k_ = _pad_axis(k_, 8, 1)
-        v_ = _pad_axis(v_, 8, 1)
-        pos_ = _pad_axis(pos_, 8, 1, value=POS_SENTINEL)
-        q_, k_, v_ = (_pad_axis(x, _LANES, 3) for x in (q_, k_, v_))
-        if quant:
-            k_scale_pages = _pad_axis(k_scale_pages, 8, 1)
-            v_scale_pages = _pad_axis(v_scale_pages, 8, 1)
-    kp, psp, Dp = q_.shape[1], k_.shape[1], k_.shape[3]
+    def page_map(b, j, bt, fr):
+        return (bt[b, jnp.minimum(fr[b] + j, nb - 1)], 0, 0, 0)
 
-    def page_map(b, h, j, bt, fr):
-        blk = jnp.minimum(fr[b] + j, nb - 1)
-        return (bt[b, blk], 0, h, 0)
+    def row_map(b, j, bt, fr):                    # (P, 1, ps) / (P, Hkv, ps)
+        return (bt[b, jnp.minimum(fr[b] + j, nb - 1)], 0, 0)
 
-    def pos_map(b, h, j, bt, fr):
-        blk = jnp.minimum(fr[b] + j, nb - 1)
-        return (bt[b, blk], 0)
-
-    def q_map(b, h, j, bt, fr):
-        return (b, 0, h, 0)
+    def q_map(b, j, bt, fr):
+        return (b, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, kp, G, Dp), q_map),
-        pl.BlockSpec((1, kp), lambda b, h, j, bt, fr: (b, 0)),
-        pl.BlockSpec((1, psp, 1, Dp), page_map),
-        pl.BlockSpec((1, psp, 1, Dp), page_map),
-        pl.BlockSpec((1, psp), pos_map),
+        pl.BlockSpec((1, Hkv, rows, D), q_map),
+        pl.BlockSpec((1, rows, 1), lambda b, j, bt, fr: (b, 0, 0)),
+        pl.BlockSpec((1, Hkv, ps, D), page_map),
+        pl.BlockSpec((1, Hkv, ps, D), page_map),
+        pl.BlockSpec((1, 1, ps), row_map),
     ]
-    operands = [q_, qp_, k_, v_, pos_]
+    operands = [q_, qp_, k_pages, v_pages, pos_pages[:, None, :]]
     if quant:
-        def scale_map(b, h, j, bt, fr):          # (P, ps, Hkv): 3-d blocks
-            blk = jnp.minimum(fr[b] + j, nb - 1)
-            return (bt[b, blk], 0, h)
-
-        in_specs += [pl.BlockSpec((1, psp, 1), scale_map),
-                     pl.BlockSpec((1, psp, 1), scale_map)]
+        in_specs += [pl.BlockSpec((1, Hkv, ps), row_map),
+                     pl.BlockSpec((1, Hkv, ps), row_map)]
         operands += [k_scale_pages, v_scale_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hkv, nb),
+        grid=(B, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kp, G, Dp), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((kp * G, Dp), jnp.float32),
-            pltpu.VMEM((kp * G, _LANES), jnp.float32),
-            pltpu.VMEM((kp * G, _LANES), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, rows, D), q_map),
+        scratch_shapes=_scratch(Hkv, rows, D),
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, nb=nb, window=window, cap=attn_cap,
-                          scale=scale, G=G, quant=quant),
+                          scale=scale, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kp, Hq, Dp), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), first, *operands)
-    return out[:, :k, :, :D]
+    return _unfold_heads(out, k, G)
 
 
 def paged_decode_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
                            q_pos, window=None, attn_cap=None,
                            k_scale_pages=None, v_scale_pages=None,
-                           interpret=INTERPRET):
+                           interpret=None):
     """Single-token decode over the paged pool: the ``k == 1`` q tile of
     :func:`paged_prefill_attention` (kept as the decode-path entry point).
 

@@ -10,11 +10,14 @@ in-kernel, so each (bk, bn) weight tile of every plane is read exactly once.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 
 def _kernel(x_ref, b_ref, a_ref, o_ref, acc_ref, *, k_steps: int,
@@ -41,7 +44,8 @@ def _kernel(x_ref, b_ref, a_ref, o_ref, acc_ref, *, k_steps: int,
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def binary_matmul_pallas(x: jnp.ndarray, planes: jnp.ndarray,
                          alpha: jnp.ndarray, *, bm: int = 128, bn: int = 128,
-                         bk: int = 128, interpret: bool = True) -> jnp.ndarray:
+                         bk: int = 128, interpret: Optional[bool] = None
+                         ) -> jnp.ndarray:
     """x: (M, K); planes: (P, K, N) int8 {-1,+1}; alpha: (P, N) f32."""
     M, K = x.shape
     P, _, N = planes.shape
@@ -58,5 +62,5 @@ def binary_matmul_pallas(x: jnp.ndarray, planes: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, planes, alpha.reshape(1, P, N))

@@ -8,11 +8,13 @@ fine-tuning path where the same weight tile is fake-quantized every step.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import interpret_mode
 from repro.quant.linear_quant import FULL_BITS
 
 
@@ -30,7 +32,7 @@ def _kernel(x_ref, s_ref, lv_ref, b_ref, o_ref, *, full_bits: float):
                    static_argnames=("bm", "bn", "interpret", "full_bits"))
 def fake_quant_pallas(x: jnp.ndarray, scale: jnp.ndarray, levels: jnp.ndarray,
                       bits: jnp.ndarray, *, bm: int = 256, bn: int = 128,
-                      interpret: bool = True,
+                      interpret: Optional[bool] = None,
                       full_bits: float = FULL_BITS) -> jnp.ndarray:
     """x: (M, N); scale/levels/bits: (N,) per-channel.  ``full_bits`` is the
     pass-through threshold, threaded from quant.linear_quant.FULL_BITS so the
@@ -48,5 +50,5 @@ def fake_quant_pallas(x: jnp.ndarray, scale: jnp.ndarray, levels: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, scale.reshape(1, N), levels.reshape(1, N), bits.reshape(1, N))

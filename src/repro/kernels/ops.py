@@ -1,12 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handle non-aligned shapes by padding to the block grid, dispatch between the
-Pallas kernel (interpret=True on CPU, compiled on TPU) and the pure-jnp
-reference, and expose a single `use_pallas` switch the serving/QAT paths use.
+Pallas kernel (interpreted on CPU, compiled on TPU: kernels/backend.py) and
+the pure-jnp reference, and expose a single `use_pallas` switch the
+serving/QAT paths use.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import pack, ref
@@ -15,10 +15,6 @@ from repro.kernels.fake_quant import fake_quant_pallas
 from repro.kernels.packed_matmul import packed_matmul_pallas
 from repro.kernels.quant_matmul import quant_matmul_pallas
 from repro.quant.linear_quant import FULL_BITS
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not _ON_TPU
-
 
 def _pad_to(x, m, axis):
     pad = (-x.shape[axis]) % m
@@ -38,8 +34,7 @@ def quant_matmul(x, qw, scale, *, bm=128, bn=128, bk=128, use_pallas=True):
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
     wp = _pad_to(_pad_to(qw, bk, 0), bn, 1)
     sp = _pad_to(scale, bn, 0)
-    y = quant_matmul_pallas(xp, wp, sp, bm=bm, bn=bn, bk=bk,
-                            interpret=INTERPRET)
+    y = quant_matmul_pallas(xp, wp, sp, bm=bm, bn=bn, bk=bk)
     return y[:M, :N]
 
 
@@ -63,7 +58,7 @@ def packed_matmul(x, pw, scale, *, store_bits, bm=128, bn=128, bk=128,
     wp = _pad_to(_pad_to(pw, bk // f, 0), bn, 1)
     sp = _pad_to(scale, bn, 0)
     y = packed_matmul_pallas(xp, wp, sp, store_bits=store_bits, bm=bm, bn=bn,
-                             bk=bk, interpret=INTERPRET)
+                             bk=bk)
     return y[:M, :N]
 
 
@@ -104,8 +99,7 @@ def binary_matmul(x, planes, alpha, *, bm=128, bn=128, bk=128,
     xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
     pp = _pad_to(_pad_to(planes, bk, 1), bn, 2)
     ap = _pad_to(alpha, bn, 1)
-    y = binary_matmul_pallas(xp, pp, ap, bm=bm, bn=bn, bk=bk,
-                             interpret=INTERPRET)
+    y = binary_matmul_pallas(xp, pp, ap, bm=bm, bn=bn, bk=bk)
     return y[:M, :N]
 
 
@@ -124,6 +118,5 @@ def fake_quant_channels(x, scale, levels, bits, *, bm=256, bn=128,
     sp = jnp.where(pad1(scale) == 0, 1.0, pad1(scale)) if N % bn else scale
     lp = jnp.where(pad1(levels) == 0, 1.0, pad1(levels)) if N % bn else levels
     bp = pad1(bits)
-    y = fake_quant_pallas(xp, sp, lp, bp, bm=bm, bn=bn, interpret=INTERPRET,
-                          full_bits=full_bits)
+    y = fake_quant_pallas(xp, sp, lp, bp, bm=bm, bn=bn, full_bits=full_bits)
     return y[:M, :N]

@@ -52,6 +52,7 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # storage width -> values per byte
 SUB8_FACTORS = {2: 4, 4: 2}
@@ -172,28 +173,45 @@ class PackedWeight:
 
     # ------------------------------------------------------------- dequant
     def dequant(self) -> jnp.ndarray:
-        """Reconstruct the dequantized (..., K, N) weight (jit-safe)."""
-        lead: Tuple[int, ...] = ()
-        for part in self.parts:
-            if part:
-                lead = part[0].shape[:-2]
-                break
-        out = jnp.zeros(lead + (self.k, self.n), jnp.float32)
+        """Reconstruct the dequantized (..., K, N) weight (jit-safe).
+
+        Channels are reassembled in policy order from static slices of the
+        per-bucket columns and one concatenate: a policy assigns QBNs per
+        contiguous channel group, so the slices are few, and XLA copies
+        them (a column scatter runs one index at a time on TPU)."""
+        out_dt = jnp.dtype(self.out_dtype)
+        lead = self.parts[0][0].shape[:-2]
+        cols = []
         for (name, idx), part in zip(self.buckets, self.parts):
             if name == "pruned":
-                continue
-            idx_a = jnp.asarray(idx)
-            if name == "full":
-                cols = part[0].astype(jnp.float32)
+                c = jnp.zeros(lead + (self.k, len(idx)), out_dt)
+            elif name == "full":
+                c = part[0].astype(out_dt)
             else:
                 data, scale = part
                 if name != "int8":
                     data = unpack_sub8(data, STORE_BITS[name], self.k,
                                        axis=-2)
-                cols = data.astype(jnp.float32) * \
-                    scale.astype(jnp.float32)[..., None, :]
-            out = out.at[..., idx_a].set(cols)
-        return out.astype(jnp.dtype(self.out_dtype))
+                c = (data.astype(jnp.float32) *
+                     scale.astype(jnp.float32)[..., None, :]).astype(out_dt)
+            cols.append(c)
+        pieces = [cols[b][..., off:off + size]
+                  for b, off, size in self._runs()]
+        return pieces[0] if len(pieces) == 1 else \
+            jnp.concatenate(pieces, axis=-1)
+
+    def _runs(self):
+        """Maximal runs of consecutive channels stored consecutively in one
+        bucket: (bucket position, offset in bucket, length), channel order."""
+        src = np.empty((self.n, 2), np.int64)
+        for b, (_, idx) in enumerate(self.buckets):
+            src[list(idx)] = np.stack([np.full(len(idx), b),
+                                       np.arange(len(idx))], axis=1)
+        starts = np.flatnonzero(
+            (np.diff(src[:, 0]) != 0) | (np.diff(src[:, 1]) != 1)) + 1
+        bounds = [0, *starts.tolist(), self.n]
+        return [(int(src[s, 0]), int(src[s, 1]), e - s)
+                for s, e in zip(bounds[:-1], bounds[1:])]
 
     # ----------------------------------------------------------- accounting
     def bucket_nbytes(self) -> dict:

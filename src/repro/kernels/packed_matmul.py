@@ -38,12 +38,14 @@ Invariants:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_mode
 from repro.kernels.pack import SUB8_FACTORS, extract_fields
 
 
@@ -74,7 +76,7 @@ def _kernel(x_ref, pw_ref, s_ref, o_ref, acc_ref, *, k_steps: int,
 def packed_matmul_pallas(x: jnp.ndarray, pw: jnp.ndarray, scale: jnp.ndarray,
                          *, store_bits: int, bm: int = 128, bn: int = 128,
                          bk: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (M, K); pw: (K/f, N) int8 packed (f = 8/store_bits); scale: (N,).
 
     M, K, N must be multiples of the block shape (ops.py pads; zero pad bytes
@@ -97,5 +99,5 @@ def packed_matmul_pallas(x: jnp.ndarray, pw: jnp.ndarray, scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, pw, scale.reshape(1, N))

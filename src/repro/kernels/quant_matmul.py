@@ -15,11 +15,14 @@ in ops.py).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import interpret_mode
 
 
 def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps: int):
@@ -43,7 +46,7 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps: int):
                    static_argnames=("bm", "bn", "bk", "interpret"))
 def quant_matmul_pallas(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
                         *, bm: int = 128, bn: int = 128, bk: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (M, K); qw: (K, N) int8; scale: (N,) f32.  M, K, N must be
     multiples of the block shape (ops.py pads)."""
     M, K = x.shape
@@ -61,5 +64,5 @@ def quant_matmul_pallas(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, qw, scale.reshape(1, N))

@@ -2,14 +2,20 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --smoke \
         --bits 8 --n-new 32
+
+Smoke configs initialize f32 weights; published widths initialize bf16 (a
+gemma2-2b f32 tree alone is 12.8 GB).  The initialized tree is handed to
+the engine and never bound here, so once the engine has quantized it
+nothing keeps the source copy alive beside the quantized one.
 """
 import argparse
 
 import jax
-import numpy as np
+import jax.numpy as jnp
 
 from repro.configs import get
 from repro.data import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import LM
 from repro.quant.policy import QuantPolicy
 from repro.serve import ServeEngine
@@ -37,15 +43,16 @@ def main():
     if cfg.frontend == "audio_stub":
         raise SystemExit("audio_stub archs need frame embeddings; use the "
                          "dry-run for musicgen serving shapes")
+    enable_compile_cache()
     model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
     policy = graph = None
     if args.bits > 0:
         graph = model.graph(seq_len=args.prompt_len, batch=args.batch)
         policy = QuantPolicy.uniform(graph, args.bits)
 
-    eng = ServeEngine(model, params, policy=policy, graph=graph,
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
+    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0), dtype=dtype),
+                      policy=policy, graph=graph,
                       max_len=args.prompt_len + args.n_new,
                       attn_impl=args.attn_impl,
                       kv_bits=args.kv_bits or None)

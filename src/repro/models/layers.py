@@ -222,13 +222,17 @@ def attention_ref(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
 def paged_gather(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
     """Gather per-sequence KV through block tables.
 
-    pages: (P, page_size, ...) physical pool; block_tables: (B, nb) int32
-    physical page ids (logical block order).  Returns (B, nb*page_size, ...)
-    -- each sequence's pages flattened back into logical position order.
-    Unmapped blocks point at the trash page (id 0); its slots carry sentinel
-    positions, so the attention mask rejects them.
+    pages: (P, page_size) position pool, or a head-major (P, Hkv, page_size,
+    ...) K/V / scale pool; block_tables: (B, nb) int32 physical page ids
+    (logical block order).  Returns (B, nb*page_size[, Hkv, ...]) -- each
+    sequence's pages flattened back into logical position order, heads
+    after positions like a dense cache.  Unmapped blocks point at the trash
+    page (id 0); its slots carry sentinel positions, so the attention mask
+    rejects them.
     """
-    g = pages[block_tables]                      # (B, nb, ps, ...)
+    g = pages[block_tables]                      # (B, nb, [Hkv,] ps, ...)
+    if g.ndim > 3:
+        g = jnp.moveaxis(g, 2, 3)                # (B, nb, ps, Hkv, ...)
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
@@ -239,10 +243,10 @@ def paged_attention(q, k_pages, v_pages, pos_pages, block_tables, *, q_pos,
 
     q: (B, Sq, Hq, D) -- ``Sq == 1`` is the decode step, ``Sq > 1`` a
     prompt chunk whose K/V were already scattered into the pool this step;
-    ``*_pages``: (P, page_size, Hkv, D) (``pos_pages`` (P, page_size)
-    int32); block_tables: (B, nb); q_pos: (B, Sq) int32, real rows
-    left-aligned and sentinel-padded.  int8 pools carry per-(slot, head)
-    ``*_scale_pages`` (P, page_size, Hkv) f32.
+    ``k_pages`` / ``v_pages``: (P, Hkv, page_size, D) head-major
+    (``pos_pages`` (P, page_size) int32); block_tables: (B, nb); q_pos:
+    (B, Sq) int32, real rows left-aligned and sentinel-padded.  int8 pools
+    carry per-(head, slot) ``*_scale_pages`` (P, Hkv, page_size) f32.
 
     ``impl="ref"`` (default) gathers each sequence's pages into logical
     order and runs the standard masked flash attention; ``"pallas"``

@@ -162,7 +162,7 @@ class LM:
         """Self- or cross-attention + residual.  Returns (x, new_cache).
 
         block_tables (decode only): (B, nb) int32 physical page ids -- the
-        cache entry is then a paged pool (P, page_size, Hkv, hd) shared by
+        cache entry is then a paged pool (P, Hkv, page_size, hd) shared by
         the batch, written through the table and attended per sequence
         (``write_pos`` is per-sequence (B,) in that mode).  An int8 pool
         (``init_paged_cache(kv_bits=8)``) quantizes the write and carries
@@ -198,7 +198,7 @@ class LM:
             if cache is not None:
                 if block_tables is not None:   # paged write+attend (S >= 1:
                     # one decode token or a k-token prompt chunk per row)
-                    ps = cache["k"].shape[-3]
+                    ps = cache["k"].shape[-2]
                     nb = block_tables.shape[1]
                     wp = write_pos if write_pos.ndim == 2 \
                         else write_pos[:, None]            # (B, S)
@@ -211,22 +211,22 @@ class LM:
                     phys = jnp.where(wp == POS_SENTINEL, TRASH_PAGE, phys)
                     fp = phys.reshape(-1)                  # flat (B*S,)
                     fs = (wp % ps).reshape(-1)
+                    # head-major pool (P, Hkv, ps, ...): [fp, :, fs] picks
+                    # one (Hkv, ...) slot per written token
                     new_cache = dict(cache)
                     if cache["k"].dtype == jnp.int8:   # quantized page write
                         for key, val in (("k", k), ("v", v)):
                             qv, sv = _kv_quant(val)
-                            new_cache[key] = cache[key].at[fp, fs].set(
+                            new_cache[key] = cache[key].at[fp, :, fs].set(
                                 qv.reshape((-1,) + qv.shape[2:]))
                             new_cache[key + "_s"] = \
-                                cache[key + "_s"].at[fp, fs].set(
+                                cache[key + "_s"].at[fp, :, fs].set(
                                     sv.reshape((-1,) + sv.shape[2:]))
                     else:
-                        new_cache["k"] = cache["k"].at[fp, fs].set(
-                            k.reshape((-1,) + k.shape[2:])
-                            .astype(cache["k"].dtype))
-                        new_cache["v"] = cache["v"].at[fp, fs].set(
-                            v.reshape((-1,) + v.shape[2:])
-                            .astype(cache["v"].dtype))
+                        for key, val in (("k", k), ("v", v)):
+                            new_cache[key] = cache[key].at[fp, :, fs].set(
+                                val.reshape((-1,) + val.shape[2:])
+                                .astype(cache[key].dtype))
                     new_cache["pos"] = cache["pos"].at[fp, fs].set(
                         wp.reshape(-1).astype(jnp.int32))
                     out = paged_attention(
@@ -487,7 +487,9 @@ class LM:
 
         * ``"paged"`` (attn / local_attn): a pool of ``num_pages`` physical
           pages of ``page_size`` KV slots shared by all sequences --
-          ``{"k","v": (R, P, ps, Hkv, hd), "pos": (R, P, ps) int32}``.
+          ``{"k","v": (R, P, Hkv, ps, hd), "pos": (R, P, ps) int32}``,
+          head-major: each head's (ps, hd) page plane is contiguous,
+          the block the Pallas page walk DMAs (kernels/attention.py).
           ``pos`` starts at ``POS_SENTINEL`` so unwritten slots are masked;
           page 0 is the trash page (serve/paged_kv.py owns the lifecycle).
         * ``"memory"`` (cross_attn) / ``"state"`` (mamba): dense per-slot
@@ -495,7 +497,7 @@ class LM:
           layouts, since neither grows with decoded length.
 
         ``kv_bits=8`` stores K/V pages int8 with one scale page per KV page
-        (``"k_s","v_s": (R, P, ps, Hkv) f32``, per-(slot, head) scales) --
+        (``"k_s","v_s": (R, P, Hkv, ps) f32``, per-(head, slot) scales) --
         the same quantizer as the dense cache (``_kv_quant``), so paged
         serving is bit-identical to dense int8 decode; the Pallas decode
         kernel dequantizes the pages in VMEM.
@@ -514,18 +516,18 @@ class LM:
 
         def kv_pages():
             one = {
-                "k": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+                "k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                 cfg.hdim), kv_dt),
-                "v": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+                "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                 cfg.hdim), kv_dt),
                 "pos": jnp.full((num_pages, page_size), POS_SENTINEL,
                                 jnp.int32),
             }
             if kv_bits == 8:
-                one["k_s"] = jnp.ones((num_pages, page_size,
-                                       cfg.n_kv_heads), jnp.float32)
-                one["v_s"] = jnp.ones((num_pages, page_size,
-                                       cfg.n_kv_heads), jnp.float32)
+                one["k_s"] = jnp.ones((num_pages, cfg.n_kv_heads,
+                                       page_size), jnp.float32)
+                one["v_s"] = jnp.ones((num_pages, cfg.n_kv_heads,
+                                       page_size), jnp.float32)
             return one
 
         caches = []

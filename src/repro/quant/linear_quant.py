@@ -133,6 +133,18 @@ def dequant_int8(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * scale
 
 
+def _take_cols(w: jnp.ndarray, idx) -> jnp.ndarray:
+    """``w[..., idx]`` for an ascending channel list, as static slices of
+    its consecutive runs: policies assign QBNs per contiguous channel group,
+    so the runs are few, and a last-axis gather runs one index at a time
+    on TPU."""
+    idx = np.asarray(idx)
+    cuts = np.flatnonzero(np.diff(idx) != 1) + 1
+    pieces = [w[..., int(r[0]):int(r[-1]) + 1]
+              for r in np.split(idx, cuts)]
+    return pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, -1)
+
+
 def quant_pack_sub8(w: jnp.ndarray, bits, axis: int = -1):
     """Quantize to the *bucketed sub-byte* stored layout + per-channel scales.
 
@@ -165,8 +177,10 @@ def quant_pack_sub8(w: jnp.ndarray, bits, axis: int = -1):
     assert axis % w.ndim == w.ndim - 1, \
         "packed layout requires output channels on the last axis"
     n, k = w.shape[-1], w.shape[-2]
-    wf = w.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(wf), axis=tuple(range(w.ndim - 1)))     # (n,)
+    # |w| max is exact in w's own dtype; only one bucket's columns are ever
+    # widened to f32 at a time (a published-width unembed is GBs in f32)
+    amax = jnp.max(jnp.abs(w), axis=tuple(range(w.ndim - 1))).astype(
+        jnp.float32)                                                # (n,)
     b = np.rint(np.broadcast_to(
         np.asarray(bits, np.float32), (n,))).astype(np.int64)
     members: dict = {}
@@ -184,7 +198,7 @@ def quant_pack_sub8(w: jnp.ndarray, bits, axis: int = -1):
             parts.append((jnp.zeros(w.shape[:-2] + (k, 0), jnp.int8),))
             continue
         idx_a = jnp.asarray(idx)
-        cols = wf[..., idx_a]
+        cols = _take_cols(w, idx).astype(jnp.float32)
         if name == "full":
             parts.append((cols.astype(jnp.bfloat16),))
             continue

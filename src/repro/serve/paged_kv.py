@@ -27,7 +27,8 @@ Invariants the rest of the stack relies on:
   unreachable.  Freeing is O(1) -- no scrub on release.
 * **Layout contract** (built by ``LM.init_paged_cache``, keyed by
   ``LMConfig.cache_kinds()``): ``"paged"`` entries are
-  ``{"k","v": (R, P, page_size, Hkv, hd), "pos": (R, P, page_size)}``;
+  ``{"k","v": (R, P, Hkv, page_size, hd), "pos": (R, P, page_size)}``
+  (int8 pools add ``"k_s","v_s": (R, P, Hkv, page_size)``);
   ``"memory"`` / ``"state"`` entries are the dense per-slot caches with the
   batch axis sized to the number of scheduler slots.  ``R`` is the scan
   stack (n_repeat); all repeats of a block write the same positions, so one
@@ -234,10 +235,18 @@ def write_prefill(paged_cache, dense_cache, kinds: Sequence[str], slot: int,
             phys = jnp.asarray(blocks_np[p // page_size])
             pslot = jnp.asarray(p % page_size)
             j = jnp.asarray(j)
-            # pool planes are (R, P, ps, ...) and dense planes (R, 1, S, ...)
-            # with matching trailing dims, so one scatter form covers them all
-            entry = {key: pool[key].at[:, phys, pslot].set(
-                pre[key][:, 0, j].astype(pool[key].dtype)) for key in pool}
+            # dense planes are (R, 1, S[, Hkv, ...]); pool planes are
+            # (R, P, ps) for pos and head-major (R, P, Hkv, ps, ...) for
+            # k/v/scales, whose [:, phys, :, pslot] scatter takes the
+            # written slots first: (n, R, Hkv, ...)
+            entry = {}
+            for key in pool:
+                val = pre[key][:, 0, j].astype(pool[key].dtype)
+                if key == "pos":
+                    entry[key] = pool[key].at[:, phys, pslot].set(val)
+                else:
+                    entry[key] = pool[key].at[:, phys, :, pslot].set(
+                        jnp.moveaxis(val, 1, 0))
             out.append(entry)
         elif kind == "memory":
             out.append({key: pool[key].at[:, slot].set(

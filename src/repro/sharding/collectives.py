@@ -31,9 +31,7 @@ def compressed_allreduce(tree, axis_name: str = "pod"):
     Call inside shard_map (manual over axis_name).  Scalars and tiny leaves
     (< 1KiB) go through a plain psum -- compression overhead isn't worth it.
     """
-    # jax >= 0.6 has lax.axis_size; 0.4.x spells it psum(1, axis)
-    n = jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size") \
-        else jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(g):
         if g.ndim == 0 or g.size < 256:

@@ -22,14 +22,9 @@ def f(g, tiny):
     out = compressed_allreduce({"g": g[0], "t": tiny[0]}, "pod")
     return out["g"], out["t"]
 
-if hasattr(jax, "shard_map"):                     # jax >= 0.6 API
-    smap = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                         out_specs=(P(), P()), axis_names={"pod"},
-                         check_vma=False)
-else:                                             # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-    smap = shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                     out_specs=(P(), P()), check_rep=False)
+smap = jax.shard_map(f, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                     out_specs=(P(), P()), axis_names={"pod"},
+                     check_vma=False)
 cg, ct = jax.jit(smap)(g, tiny)
 
 exact_g = np.mean(np.asarray(g), axis=0)

@@ -27,7 +27,8 @@ from repro.sharding.ctx import sharding_rules
 from repro.sharding.specs import to_named
 import dataclasses as dc
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 spec = get("gemma2-2b")
 # shrink the production config so the 8-device compile is fast but the
 # sharding logic is exercised on the same code path
