@@ -248,6 +248,7 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
         out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
         scratch_shapes=_scratch(Hkv, rq, D),
         interpret=interpret,
+        name="flash_attention",
     )(q_, k_, v_, qp_, kp_)
     return _unfold_heads(out, Sq, G)
 
@@ -371,6 +372,7 @@ def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_tables.astype(jnp.int32), first, *operands)
     return _unfold_heads(out, k, G)
 

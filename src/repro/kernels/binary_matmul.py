@@ -63,4 +63,5 @@ def binary_matmul_pallas(x: jnp.ndarray, planes: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret_mode(interpret),
+        name="binary_matmul_pallas",
     )(x, planes, alpha.reshape(1, P, N))

@@ -51,4 +51,5 @@ def fake_quant_pallas(x: jnp.ndarray, scale: jnp.ndarray, levels: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret_mode(interpret),
+        name="fake_quant_pallas",
     )(x, scale.reshape(1, N), levels.reshape(1, N), bits.reshape(1, N))

@@ -100,4 +100,5 @@ def packed_matmul_pallas(x: jnp.ndarray, pw: jnp.ndarray, scale: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret_mode(interpret),
+        name="packed_matmul_pallas",
     )(x, pw, scale.reshape(1, N))

@@ -65,4 +65,5 @@ def quant_matmul_pallas(x: jnp.ndarray, qw: jnp.ndarray, scale: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret_mode(interpret),
+        name="quant_matmul_pallas",
     )(x, qw, scale.reshape(1, N))

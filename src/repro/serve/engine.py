@@ -33,9 +33,10 @@ weight-load time, with per-layer dispatch between two weight stores:
 * ``weight_store="packed"`` -- the bucketed sub-byte layout
   (quant.apply.apply_policy_packed): channels with QBN <= 4 bit-packed
   along K (kernels/pack.py), 5..8 int8, > 8 bf16, so stored bytes track the
-  searched policy.  ``models.layers.deq`` unpacks at use; on TPU the unpack
-  fuses into the consuming matmul (kernels/packed_matmul.py is the
-  explicit-tiling version, benchmarked in benchmarks/packed_vs_int8.py).
+  searched policy.  ``models.layers.deq`` unpacks at use, on TPU into a
+  bf16 copy of each weight per step, not inside the matmul (its docstring;
+  kernels/packed_matmul.py is the explicit-tiling version that reads the
+  stored width, benchmarked in benchmarks/packed_vs_int8.py).
 
 Both stores serve through *both* execution models unchanged -- the store is
 a property of the parameters, not of the cache layout (invariant guarded by
@@ -126,6 +127,8 @@ class ServeEngine:
                     graph, [policy.act_bits.get(l.name, float(FULL_BITS))
                             for l in graph.layers])
         self.params = params
+        # the latest serve() session's counters, set before its loop runs
+        self.stats: Optional[ServeStats] = None
         # trace counters: each jit *trace* (i.e. each compiled variant) runs
         # the python wrapper once, cache hits never do -- so these count
         # compiled variants per entry point.  The chunked step loop is
@@ -468,7 +471,9 @@ class ServeEngine:
 
         Returns ``{"outputs": {rid: np.ndarray}, "stats": ServeStats,
         "shed": [rid, ...]}`` -- shed requests (reported in both
-        ``shed`` and ``stats.shed``) have empty output streams.
+        ``shed`` and ``stats.shed``) have empty output streams.  The same
+        ``ServeStats`` is ``self.stats`` from before the loop runs, so a
+        session ended by an exception still leaves its counters readable.
         """
         kinds = self.model.cfg.cache_kinds()
         if not all(kd == "paged" for kd in kinds):
@@ -512,9 +517,12 @@ class ServeEngine:
                         chunk=chunk, budget=budget,
                         reclaim=self._reclaim_window(kinds), spec=spec,
                         overlap=overlap)
-        loop.run()
-        stats.n_requests = frontend.n_submitted
-        stats.shed = list(frontend.shed)
+        self.stats = stats
+        try:
+            loop.run()
+        finally:
+            stats.n_requests = frontend.n_submitted
+            stats.shed = list(frontend.shed)
         outputs = {rid: np.asarray(toks, np.int32)
                    for rid, toks in loop.outputs.items()}
         for rid in frontend.shed:

@@ -22,7 +22,13 @@
   draft tokens are counted here and *nowhere else*: they never touch
   ``tokens_out``, TTFT, or the decode rate), ``acceptance_rate`` and
   ``spec_tokens_per_step`` -- the multi-token-decode win
-  (docs/speculative.md has the math these feed).
+  (docs/speculative.md has the math these feed);
+* **step shape** -- ``step_slots`` / ``step_tokens`` and their ratio
+  ``step_fill`` (real tokens over the ``rows x width`` slots dispatched:
+  padding of partial chunks and of decode lanes riding a chunk-wide
+  step), ``pages_in_use`` / ``peak_pages``, ``requeues``.  The step loop
+  fills them where it writes the same counts as the args of its
+  ``serve.step`` profiler span (``serve/step_loop.py`` ``SPANS``).
 
 Host-side plain data: no jax arrays, picklable, safe to compare across
 runs.  ``serve/engine.py`` re-exports it for backward compatibility.
@@ -81,6 +87,11 @@ class ServeStats:
     requeues: int = 0               # chunked: prefills preempted + requeued
     reclaimed_pages: int = 0        # out-of-window pages returned mid-run
     peak_pages: int = 0             # high-water mark of pool pages in use
+    pages_in_use: int = 0           # chunked: pool pages held after a step
+    # chunked: slots dispatched (rows x width, summed over steps) and the
+    # real prompt + decode tokens in them
+    step_slots: int = 0
+    step_tokens: int = 0
     # ---- speculative decode (run(speculative=True)) ----
     spec_steps: int = 0             # verify steps with >= 1 speculating lane
     spec_lane_steps: int = 0        # per-lane verify events (lane x step)
@@ -98,6 +109,11 @@ class ServeStats:
         # both sides, so this is the steady-state decode-batch rate
         return ((self.tokens_out - self.prefill_tokens) / self.decode_s
                 if self.decode_s else 0.0)
+
+    @property
+    def step_fill(self) -> float:
+        """Real tokens over dispatched slots (0.0 before any step)."""
+        return self.step_tokens / self.step_slots if self.step_slots else 0.0
 
     @property
     def acceptance_rate(self) -> float:

@@ -37,6 +37,11 @@ Speculative decode rides the same class but steps synchronously
 batched device sampler, which draws every lane's whole candidate span
 and the rng key for every possible acceptance length in one call.
 
+Every phase runs under a profiler span named in ``SPANS`` below; the
+``serve.step`` span carries the step's counts as args, and
+``ServeStats`` adds up the same counts (``_count``), so a trace and the
+counters cannot disagree (docs/serving.md, "Tracing the step loop").
+
 jit-variant boundedness is unchanged: the loop adds no ``model_step``
 shapes (2 per run: mixed width + pure-decode width), and the sampler
 compiles at most two shapes of its own ((R, 1, V) plain, (R, k+1, V)
@@ -49,13 +54,43 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serve import paged_kv
 from repro.serve.frontend import FrontEnd
 from repro.serve.scheduler import Request, Scheduler
 from repro.serve.stats import ServeStats
 
-__all__ = ["StepLoop", "PENDING"]
+__all__ = ["StepLoop", "PENDING", "SPANS"]
+
+# every host span the loop writes into a profiler trace
+# (``jax.profiler.TraceAnnotation``: inactive unless a trace is running)
+SPANS = (
+    # one StepLoop.step; args are its dispatch's counts: step, rows, width,
+    # prompt_tokens, decode_tokens, slots (rows x width; 0 when every
+    # planned slot was preempted and nothing ran), fresh_pages,
+    # pages_in_use, requeued -- the numbers ServeStats adds up
+    "serve.step",
+    "serve.admit",     # child of serve.step: first-chunk admissions
+    "serve.plan",      # child of serve.step: Scheduler.plan_step
+    "serve.scrub",     # child of serve.step: scrubbing the fresh pages
+    "serve.draft",     # child of serve.step: speculative draft pass
+    "serve.dispatch",  # child of serve.step: building the step's inputs
+    # inside serve.dispatch and serve.finish: the calls that hand the step
+    # to the device (input transfers, feedback scatter, model_step,
+    # sampler, rng-key gather); they return before the device has run
+    # them, but may wait on the runtime for the step before
+    "serve.enqueue",
+    # child of serve.step: the host's record of the sampled tokens (lane
+    # bookkeeping; speculative accept/rollback and its stream callbacks)
+    "serve.finish",
+    # one step's blocking token sync, plus the stream callbacks of plain
+    # steps: inside serve.finish when the pipeline retires the step
+    # before, alone at idle and at exit
+    "serve.retire",
+    "serve.pump",      # FrontEnd.pump: arrivals into the scheduler
+    "serve.wait",      # FrontEnd.wait: idle until the next arrival
+)
 
 # placeholder for a sampled-but-not-yet-synced token in host bookkeeping
 # (scheduler ``out`` lists and the output streams); never fed to the model
@@ -111,7 +146,8 @@ class StepLoop:
         queued, or running."""
         try:
             while True:
-                now, released = self.fe.pump(self.sched)
+                with TraceAnnotation("serve.pump"):
+                    now, released = self.fe.pump(self.sched)
                 for req in released:
                     if req.prompt_len + req.n_new > self.eng.max_len:
                         raise ValueError(
@@ -122,7 +158,8 @@ class StepLoop:
                     if self.fe.n_scheduled == 0:
                         break
                     self._retire()        # flush streams before idling
-                    self.fe.wait(now)
+                    with TraceAnnotation("serve.wait"):
+                        self.fe.wait(now)
                     continue
                 self.step(now)
         finally:
@@ -130,6 +167,10 @@ class StepLoop:
 
     def step(self, now: float) -> None:
         """One engine step: admit, plan, dispatch, sample, account."""
+        with TraceAnnotation("serve.step") as span:
+            self._step(now, span)
+
+    def _step(self, now: float, span) -> None:
         eng, sched, stats, spec = self.eng, self.sched, self.stats, self.spec
         k = spec["k"] if spec else 0
         W = max(self.chunk, k + 1) if spec else self.chunk
@@ -138,35 +179,39 @@ class StepLoop:
                 sched.reclaim_out_of_window(self.reclaim))
         # ---- admission: a request joins when its first chunk fits
         fresh = []
-        while (adm := sched.try_admit_chunked(self.chunk)) is not None:
-            req, slot, pages = adm
-            fresh += pages
-            self._admit(req, slot, now)
+        with TraceAnnotation("serve.admit"):
+            while (adm := sched.try_admit_chunked(self.chunk)) is not None:
+                req, slot, pages = adm
+                fresh += pages
+                self._admit(req, slot, now)
         if not sched.running_slots():
             raise paged_kv.PagesExhausted(
                 "queued request cannot ever be admitted: pool of "
                 f"{self.num_pages} pages (page_size={self.page_size}) is "
                 "too small for its first chunk + decode headroom")
         t0 = self.fe.now()
-        plan = sched.plan_step(self.chunk, self.budget, draft_k=k)
-        stats.requeues += len(plan["requeued"])
+        with TraceAnnotation("serve.plan"):
+            plan = sched.plan_step(self.chunk, self.budget, draft_k=k)
         # a request admitted above may have been preempted inside this very
         # plan_step: its admission pages are back on the free list (possibly
         # re-allocated -- then they are in plan["fresh"] under the new
         # owner), so drop the stale aliases from the scrub set
         drop = set(plan["freed"])
-        fresh = [p for p in fresh if p not in drop]
+        fresh = [p for p in fresh if p not in drop] + plan["fresh"]
         # scrub unconditionally: admission pages must be sentinel-clean
         # before any later step writes chunks into them, even if this step
         # is abandoned below.  The draft cache shares the block tables, so
         # it scrubs the same pages.
-        self.cache = paged_kv.scrub_pages(self.cache, self.kinds,
-                                          fresh + plan["fresh"])
-        if spec:
-            spec["cache"] = paged_kv.scrub_pages(
-                spec["cache"], self.kinds, fresh + plan["fresh"])
+        with TraceAnnotation("serve.scrub"):
+            self.cache = paged_kv.scrub_pages(self.cache, self.kinds,
+                                              fresh)
+            if spec:
+                spec["cache"] = paged_kv.scrub_pages(
+                    spec["cache"], self.kinds, fresh)
         if not plan["sample"] and not plan["chunked"]:
-            return                  # every planned slot was preempted
+            # every planned slot was preempted: nothing dispatched
+            self._count(span, plan, 0, len(fresh))
+            return
         # pure-decode steps run the (R, 1) column slice -- a full-width
         # step would burn masked lanes per slot once every prompt is in.
         # jit variants stay bounded per (max_slots, chunk, pool shape[,
@@ -180,34 +225,44 @@ class StepLoop:
             # every decode lane's feedback token, and proposes each
             # speculating lane's draft tokens, which fill the placeholder
             # verify columns (engine._draft_propose documents the pass)
-            drafts = eng._draft_propose(spec, plan, sched, spec_lanes,
-                                        W if plan["chunked"] else 2)
+            with TraceAnnotation("serve.draft"):
+                drafts = eng._draft_propose(spec, plan, sched, spec_lanes,
+                                            W if plan["chunked"] else 2)
             for i, cols in spec_lanes.items():
                 tokens[i, 1:cols] = drafts[i][:cols - 1]
-        tok_in = jnp.asarray(tokens[:, :w])
-        if spec is None and plan["decode"]:
+        with TraceAnnotation("serve.dispatch"):
+            toks_np = tokens[:, :w]
+            pos_np = plan["positions"][:, :w]
+            tables_np = sched.tables.as_array()
             # decode feedback stays exact: the host's view of these tokens
             # is a PENDING placeholder (plain mode never syncs values into
             # the scheduler, pipelined or not), the device value is
             # authoritative.  Spec mode records real values and skips this.
-            rows_d = jnp.asarray(np.asarray(plan["decode"], np.int32))
-            tok_in = tok_in.at[rows_d, 0].set(self._last_tok[rows_d])
-        logits, self.cache = eng._model_step(
-            eng.params, tok_in,
-            jnp.asarray(plan["positions"][:, :w]),
-            jnp.asarray(plan["slot_map"]), self.cache,
-            jnp.asarray(sched.tables.as_array()),
-            jnp.asarray(plan["logit_cols"]),
-            eng.act_bits, attn_impl=eng.attn_impl)
-        stats.chunk_prefill_tokens += sum(plan["chunked"].values())
-        # one device call samples every lane's candidate token(s) and the
-        # rng key state for every possible acceptance length
-        toks, keys_seq = eng._sample_span(logits, self._keys, self._temps)
-        if spec:
-            emitted_step = self._finish_spec(plan, spec_lanes, tokens,
-                                             toks, keys_seq)
-        else:
-            emitted_step = self._finish_plain(plan, toks, keys_seq)
+            feed = (np.asarray(plan["decode"], np.int32)
+                    if spec is None and plan["decode"] else None)
+            with TraceAnnotation("serve.enqueue"):
+                tok_in = jnp.asarray(toks_np)
+                if feed is not None:
+                    rows_d = jnp.asarray(feed)
+                    tok_in = tok_in.at[rows_d, 0].set(
+                        self._last_tok[rows_d])
+                logits, self.cache = eng._model_step(
+                    eng.params, tok_in, jnp.asarray(pos_np),
+                    jnp.asarray(plan["slot_map"]), self.cache,
+                    jnp.asarray(tables_np),
+                    jnp.asarray(plan["logit_cols"]),
+                    eng.act_bits, attn_impl=eng.attn_impl)
+                # one device call samples every lane's candidate token(s)
+                # and the rng key state for every possible acceptance
+                # length
+                toks, keys_seq = eng._sample_span(logits, self._keys,
+                                                  self._temps)
+        with TraceAnnotation("serve.finish"):
+            if spec:
+                emitted_step = self._finish_spec(plan, spec_lanes, tokens,
+                                                 toks, keys_seq)
+            else:
+                emitted_step = self._finish_plain(plan, toks, keys_seq)
         dt = self.fe.now() - t0
         # chunk-carrying steps are prefill-side: their time AND their
         # sampled tokens (first tokens plus any decode lanes riding the
@@ -218,9 +273,29 @@ class StepLoop:
             stats.prefill_tokens += emitted_step
         else:
             stats.decode_s += dt
+        self._count(span, plan, w, len(fresh))
         stats.steps += 1
-        stats.peak_pages = max(stats.peak_pages,
-                               self.num_pages - 1 - sched.allocator.n_free)
+
+    def _count(self, span, plan, width: int, fresh_pages: int) -> None:
+        """Count one step once, as the ``serve.step`` span's args and into
+        :class:`ServeStats`, so the trace and the counters cannot disagree.
+        ``width`` is 0 when nothing was dispatched."""
+        sched, stats = self.sched, self.stats
+        rows = sched.n_slots
+        prompt = sum(plan["chunked"].values())
+        decode = sum(plan["spec"].get(i, 1) for i in plan["decode"])
+        in_use = self.num_pages - 1 - sched.allocator.n_free
+        span.set_metadata(
+            step=stats.steps, rows=rows, width=width, prompt_tokens=prompt,
+            decode_tokens=decode, slots=rows * width,
+            fresh_pages=fresh_pages, pages_in_use=in_use,
+            requeued=len(plan["requeued"]))
+        stats.requeues += len(plan["requeued"])
+        stats.chunk_prefill_tokens += prompt
+        stats.step_slots += rows * width
+        stats.step_tokens += prompt + decode
+        stats.pages_in_use = in_use
+        stats.peak_pages = max(stats.peak_pages, in_use)
 
     # ---------------------------------------------------------- inner steps
     def _admit(self, req: Request, slot: int, now: float) -> None:
@@ -258,8 +333,9 @@ class StepLoop:
                 done = sched.record(i, PENDING)
             rows.append((i, rid, idx, first, done))
             stats.tokens_out += 1
-        self._keys = keys_seq[jnp.arange(n), jnp.asarray(m)]
-        tok_dev = toks[:, 0]
+        with TraceAnnotation("serve.enqueue"):
+            self._keys = keys_seq[jnp.arange(n), jnp.asarray(m)]
+            tok_dev = toks[:, 0]
         self._last_tok = tok_dev
         pending = (tok_dev, rows)
         if self.overlap:
@@ -280,7 +356,8 @@ class StepLoop:
         so acceptance changes speed, never output."""
         sched, stats, spec = self.sched, self.stats, self.spec
         n = sched.n_slots
-        vals = np.asarray(toks)               # (R, C): one transfer
+        with TraceAnnotation("serve.retire"):
+            vals = np.asarray(toks)           # (R, C): one transfer
         now = self.fe.now()
         m = np.zeros((n,), np.int32)
         emitted_step = 0
@@ -327,7 +404,8 @@ class StepLoop:
                 f[i] = min(f.get(i, s.pos), s.pos)
         if spec_lanes:
             stats.spec_steps += 1
-        self._keys = keys_seq[jnp.arange(n), jnp.asarray(m)]
+        with TraceAnnotation("serve.enqueue"):
+            self._keys = keys_seq[jnp.arange(n), jnp.asarray(m)]
         return emitted_step
 
     # ----------------------------------------------------------- retirement
@@ -343,12 +421,13 @@ class StepLoop:
         host-visible: backfill PENDING output slots, fire stream
         callbacks, stamp latency."""
         tok_dev, rows = pending
-        vals = np.asarray(tok_dev)
-        now = self.fe.now()
-        for slot, rid, idx, first, done in rows:
-            tok = int(vals[slot])
-            self.outputs[rid][idx] = tok
-            self._emit(rid, idx, tok, now, first, done)
+        with TraceAnnotation("serve.retire"):
+            vals = np.asarray(tok_dev)
+            now = self.fe.now()
+            for slot, rid, idx, first, done in rows:
+                tok = int(vals[slot])
+                self.outputs[rid][idx] = tok
+                self._emit(rid, idx, tok, now, first, done)
 
     def _emit(self, rid: int, idx: int, tok: int, now: float, first: bool,
               done: bool) -> None:
