@@ -17,19 +17,23 @@ oracle both kernels are property-tested against):
 * :func:`paged_prefill_attention` -- block-table-aware attention over the
   paged KV pool (serve/paged_kv.py layout) for q tiles of ``k`` tokens per
   sequence: the chunked-prefill workhorse, and (at ``k == 1``, via the
-  :func:`paged_decode_attention` wrapper) the decode step.  The block table
-  rides in as a scalar-prefetch operand, so the BlockSpec index_map resolves
-  ``bt[seq, first[seq] + j]`` *before* each grid step and the pipeline DMAs
-  exactly that physical page HBM->VMEM -- there is no dense gather and no
-  (B, nb*page_size) intermediate.  Causal masking runs against each q row's
-  own position, so a chunk's rows attend earlier chunks' pages plus their
-  own chunk's already-written slots (chunk offsets need no extra state).
-  For sliding-window blocks, ``first`` (the oldest logical block still
-  inside the window of the tile's lowest real position, precomputed per
-  sequence) re-bases the walk: out-of-window pages are never fetched.  Walk
-  steps past a sequence's last block clip onto its final block id and mask
-  the whole tile (Pallas skips the re-fetch when consecutive steps map to
-  the same block, so the clip costs no extra HBM traffic).
+  :func:`paged_decode_attention` wrapper) the decode step.  Each sequence
+  walks only its live pages, ``[first, last]`` of its block table
+  (:func:`live_pages`): ``last`` holds the row's highest real position
+  (causality hides every key past it) and, for sliding-window blocks,
+  ``first`` is the oldest block inside the window of its lowest.  A grid
+  step is a compute block of ``ppb = 128 // page_size`` pages, one lane
+  width of slots: the block table rides in as a scalar-prefetch operand,
+  and ``ppb`` BlockSpecs per pool resolve ``bt[seq, first + i*ppb + j]``
+  *before* each step, so the pipeline DMAs exactly those physical pages
+  HBM->VMEM while the step before computes -- there is no dense gather and
+  no (B, nb*page_size) intermediate.  The step concatenates the pages into
+  one ``(ppb*page_size, D)`` K / V tile and one score tile per head.
+  Slots past the last live page are masked; steps past it repeat its
+  indices, so they fetch nothing and compute nothing, and an idle row
+  walks no page at all.  Causal masking runs against each q row's own
+  position, so a chunk's rows attend earlier chunks' pages plus their own
+  chunk's already-written slots (chunk offsets need no extra state).
 
 Block layout (what the TPU compiler accepts: the last two dims of every
 block are whole array dims or multiples of (8, 128)).  Both kernels run
@@ -40,8 +44,17 @@ reshapes or repeats anything.  A block carries every KV head -- q ``(1, Hkv,
 rows, D)``, a KV tile ``(1, Hkv, bk, D)``, a pool page ``(1, Hkv, ps, D)``
 of the head-major pool ``(P, Hkv, ps, D)`` -- and the kernel loops the heads
 with static indices.  KV positions arrive as ``(1, bk)`` rows (``(B, 1,
-Skv)`` / ``(P, 1, ps)`` arrays).  The layout is the same under the CPU
-interpreter and on TPU: interpret-mode tests trace what the chip compiles.
+Skv)`` / ``(P, 1, ps)`` arrays).  The paged kernel takes each pool as
+``ppb`` operands, one BlockSpec per page of the compute block, because
+Mosaic's DMA cannot slice one 16-slot position or scale row out of a
+pool: it lays such rows out 128 lanes wide.  The layout is the same under
+the CPU interpreter and on TPU: interpret-mode tests trace what the chip
+compiles.
+
+VMEM: the paged kernel's q / out blocks and f32 scratch grow with the q
+tile's rows (``k * Hq/Hkv``), so the wrapper sets ``vmem_limit_bytes``
+from the call's shapes (:func:`_paged_vmem_bytes`) rather than lean on
+the compiler's default scoped limit.
 
 int8 KV pages (``kv_bits=8`` pool): the kernel streams the int8 page plus
 its per-(head, slot) scale page ``(1, Hkv, ps)`` into VMEM and applies the
@@ -127,20 +140,21 @@ def _tile_mask(qp, kp, *, causal, window):
     return mask
 
 
-def _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref, *,
+def _attend_heads(q_ref, k_at, v_at, mask, acc_ref, m_ref, l_ref, *,
                   scale, cap, k_scale=None, v_scale=None):
     """One online-softmax accumulation step for every KV head of a tile.
 
-    q_ref (1, Hkv, rows, D); k_ref / v_ref (1, Hkv, bk, D); mask (rows, bk)
-    bool, shared by all heads; scratch acc (Hkv, rows, D) and lane-replicated
-    m / l (Hkv, rows, _LANES).  ``k_scale`` / ``v_scale`` (Hkv, bk): int8
-    page scales.  Mirrors the oracle's scan body: on the first tile
-    ``alpha`` is 0 and the update reduces to single-shot softmax; on tiles
-    that leave the running max unchanged ``alpha == exp(0) == 1``.
+    q_ref (1, Hkv, rows, D); ``k_at(h)`` / ``v_at(h)`` give head h's (bk, D)
+    K / V tile; mask (rows, bk) bool, shared by all heads; scratch acc (Hkv,
+    rows, D) and lane-replicated m / l (Hkv, rows, _LANES).  ``k_scale`` /
+    ``v_scale`` (Hkv, bk): int8 page scales.  Mirrors the oracle's scan
+    body: on the first tile ``alpha`` is 0 and the update reduces to
+    single-shot softmax; on tiles that leave the running max unchanged
+    ``alpha == exp(0) == 1``.
     """
     for h in range(q_ref.shape[1]):
         q = q_ref[0, h].astype(jnp.float32) * scale
-        s = jax.lax.dot_general(q, k_ref[0, h].astype(jnp.float32),
+        s = jax.lax.dot_general(q, k_at(h).astype(jnp.float32),
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if k_scale is not None:
@@ -155,7 +169,7 @@ def _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref, *,
         p = jnp.exp(s - m_safe)
         alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
         pv_in = p if v_scale is None else p * v_scale[h:h + 1]
-        pv = jax.lax.dot_general(pv_in, v_ref[0, h].astype(jnp.float32),
+        pv = jax.lax.dot_general(pv_in, v_at(h).astype(jnp.float32),
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[h] = acc_ref[h] * alpha + pv
@@ -193,8 +207,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         _init_scratch(acc_ref, m_ref, l_ref)
 
     mask = _tile_mask(qp_ref[0], kp_ref[0], causal=causal, window=window)
-    _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
-                  scale=scale, cap=cap)
+    _attend_heads(q_ref, lambda h: k_ref[0, h], lambda h: v_ref[0, h], mask,
+                  acc_ref, m_ref, l_ref, scale=scale, cap=cap)
 
     @pl.when(j == nk - 1)
     def _done():
@@ -254,31 +268,89 @@ def flash_attention(q, k, v, *, q_pos, kv_pos, causal=True, window=None,
 
 
 # --------------------------------------------- paged prefill / decode
-def _paged_kernel(bt_ref, first_ref, q_ref, qp_ref, k_ref, v_ref, pos_ref,
-                  *rest, nb, window, cap, scale, quant):
-    if quant:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-        k_scale, v_scale = ks_ref[0], vs_ref[0]              # (Hkv, ps)
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-        k_scale = v_scale = None
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+def live_pages(q_pos, *, page_size, n_blocks, window=None, xp=np):
+    """The logical blocks ``[first, first + n)`` of each row's block table
+    that a paged attention call walks: the pages any of the row's query
+    positions can attend.
 
-    @pl.when(j == 0)
+    q_pos: (B, k) int32, real positions left-aligned in ascending order and
+    padded with ``POS_SENTINEL``.  ``last`` is the block of the row's
+    highest real position -- causality hides every key past it -- and
+    ``first`` the oldest block inside the sliding window of its lowest (0
+    without a window).  A row with no real position walks nothing (``n ==
+    0``).  Pure: ``xp=np`` counts on the host (``StepLoop._count``),
+    ``xp=jnp`` feeds the kernel, so the two cannot disagree.  Returns
+    (first, n), each (B,) int32.
+    """
+    real = q_pos != POS_SENTINEL
+    last = xp.minimum(xp.max(xp.where(real, q_pos, -1), axis=1) // page_size,
+                      n_blocks - 1)
+    if window is None:
+        first = xp.zeros_like(last)
+    else:
+        first = xp.clip((q_pos[:, 0] - (window - 1)) // page_size, 0,
+                        n_blocks - 1)
+    return first, xp.maximum(last - first + 1, 0)
+
+
+def _paged_kernel(bt_ref, first_ref, n_ref, q_ref, qp_ref, *rest, ppb, ps,
+                  window, cap, scale, quant):
+    # ppb page refs per pool: K, V, positions[, K scales, V scales]
+    n_pools = 5 if quant else 3
+    pools = [rest[g * ppb:(g + 1) * ppb] for g in range(n_pools)]
+    o_ref, acc_ref, m_ref, l_ref = rest[n_pools * ppb:]
+    k_refs, v_refs, pos_refs = pools[:3]
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    live = n_ref[b] - i * ppb          # pages of this block inside the walk
+
+    @pl.when(i == 0)
     def _init():
         _init_scratch(acc_ref, m_ref, l_ref)
 
-    mask = _tile_mask(qp_ref[0], pos_ref[0], causal=True, window=window)
-    # walk steps past the last logical block were clipped onto block nb-1 by
-    # the index_map: mask the duplicate tile entirely
-    mask &= first_ref[b] + j < nb
-    _attend_heads(q_ref, k_ref, v_ref, mask, acc_ref, m_ref, l_ref,
-                  scale=scale, cap=cap, k_scale=k_scale, v_scale=v_scale)
+    @pl.when(live > 0)
+    def _block():
+        def cat(refs, h):               # ppb (ps, D) pages -> (ppb*ps, D) f32
+            return jnp.concatenate(
+                [r[0, h].astype(jnp.float32) for r in refs], axis=0)
 
-    @pl.when(j == pl.num_programs(1) - 1)
+        kp = jnp.concatenate([r[0] for r in pos_refs], axis=-1)  # (1, bk)
+        mask = _tile_mask(qp_ref[0], kp, causal=True, window=window)
+        # slots past the walk's last page (clipped onto it by the index_map,
+        # or past the table) are unattendable
+        slot = jax.lax.broadcasted_iota(jnp.int32, kp.shape, 1)
+        mask &= slot < live * ps
+        k_scale = v_scale = None
+        if quant:
+            k_scale, v_scale = (jnp.concatenate([r[0] for r in refs],
+                                                axis=-1)  # (Hkv, bk)
+                                for refs in pools[3:])
+        _attend_heads(q_ref, functools.partial(cat, k_refs),
+                      functools.partial(cat, v_refs), mask, acc_ref, m_ref,
+                      l_ref, scale=scale, cap=cap, k_scale=k_scale,
+                      v_scale=v_scale)
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _done():
         _finalize(o_ref, acc_ref, l_ref)
+
+
+def _paged_vmem_bytes(*, Hkv, rows, D, ps, ppb, q_dtype, kv_dtype, quant):
+    """VMEM a paged attention call holds: the double-buffered q / out /
+    q-position blocks and page blocks, the f32 scratch, and the f32 K / V
+    blocks and (rows, bk) score tiles one head's step builds."""
+    def tile(r, c, itemsize=4):            # a 2-d slab padded to (8, 128)
+        return (-(-r // _SUBLANES) * _SUBLANES) * (-(-c // _LANES) * _LANES) \
+            * itemsize
+    bk = ppb * ps
+    q_it, kv_it = np.dtype(q_dtype).itemsize, np.dtype(kv_dtype).itemsize
+    blocks = 2 * Hkv * tile(rows, D, q_it) + tile(rows, 1)
+    page = 2 * Hkv * tile(ps, D, kv_it) + tile(1, ps)
+    if quant:
+        page += 2 * tile(Hkv, ps)
+    scratch = Hkv * (tile(rows, D) + 2 * tile(rows, _LANES))
+    temps = 2 * tile(bk, D) + 4 * tile(rows, bk) + 2 * tile(rows, D)
+    return 2 * (blocks + ppb * page) + scratch + temps
 
 
 @functools.partial(jax.jit, static_argnames=("window", "attn_cap",
@@ -303,18 +375,21 @@ def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
     page_size) f32 and the kernel applies them in VMEM.  Returns (B, k, Hq,
     D) in q.dtype.
 
-    Grid (B, nb): step ``j`` of sequence ``b`` DMAs physical page
-    ``bt[b, min(first[b]+j, nb-1)]`` -- every KV head of it -- through an
-    index_map over the scalar-prefetched table.  ``first`` -- computed from
-    the row's *lowest* real position (column 0, thanks to left-alignment)
-    -- skips the logical blocks wholly below the sliding window, so
-    out-of-window pages never leave HBM; not-yet-grown tail blocks point at
-    the trash page whose slots are all-sentinel.  Causal masking against
-    each row's own position handles chunk offsets: a chunk token attends
-    earlier chunks' pages plus its own chunk's already-written slots, never
-    its future.  Fully padded rows (q_pos all sentinel under a window;
-    all-trash tables otherwise) produce zeros or garbage the scheduler
-    ignores.
+    The walk covers only each row's live pages ``[first, first + n)``
+    (:func:`live_pages`), ``ppb = 128 // page_size`` pages (one lane width
+    of slots) to a grid step.  Grid (B, cdiv(nb, ppb)): step ``i`` of
+    sequence ``b`` DMAs the physical pages ``bt[b, first[b] + i*ppb + j]``,
+    ``j < ppb`` -- every KV head of each, with its position row and int8
+    scale rows -- through index_maps over the scalar-prefetched table, and
+    attends them as one ``(rows, ppb*page_size)`` score tile.  Slots past
+    the row's last live page are masked; steps past it repeat the last
+    block's indices, so the pipeline fetches nothing and the kernel skips
+    them.  ``first`` re-bases the walk past the blocks wholly below a
+    sliding window, so out-of-window pages never leave HBM.  Causal masking
+    against each row's own position handles chunk offsets: a chunk token
+    attends earlier chunks' pages plus its own chunk's already-written
+    slots, never its future.  A row with no real position walks nothing
+    and returns exact zeros.
     """
     interpret = interpret_mode(interpret)
     B, k, Hq, D = q.shape
@@ -326,54 +401,68 @@ def paged_prefill_attention(q, k_pages, v_pages, pos_pages, block_tables, *,
         "int8 pools require scale pages (and f32/bf16 pools must not pass them)"
     scale = 1.0 / math.sqrt(D)
     qp = q_pos.reshape(B, k).astype(jnp.int32)
-    if window is not None:
-        # oldest logical block with any position > min_real_qp - window in
-        # it; left-alignment makes column 0 the row's lowest real position
-        # (sentinel rows clip to nb-1 and mask everything, like decode)
-        first = jnp.clip((qp[:, 0] - (window - 1)) // ps, 0, nb - 1)
-    else:
-        first = jnp.zeros((B,), jnp.int32)
+    first, n = live_pages(qp, page_size=ps, n_blocks=nb, window=window,
+                          xp=jnp)
+    ppb = min(max(1, _LANES // ps), nb)
     rows = -(-(k * G) // _SUBLANES) * _SUBLANES
     q_ = _pad_axis(_fold_heads(q, Hkv), _SUBLANES, 2)
     qp_ = _row_positions(qp, G, rows)
 
-    def page_map(b, j, bt, fr):
-        return (bt[b, jnp.minimum(fr[b] + j, nb - 1)], 0, 0, 0)
+    def page(j, b, i, fr, nr):
+        # logical block of page j of compute block i; steps past the walk
+        # repeat its last block, so the pipeline re-fetches nothing
+        i = jnp.minimum(i, jnp.maximum((nr[b] + ppb - 1) // ppb - 1, 0))
+        return jnp.minimum(fr[b] + i * ppb + j,
+                           fr[b] + jnp.maximum(nr[b] - 1, 0))
 
-    def row_map(b, j, bt, fr):                    # (P, 1, ps) / (P, Hkv, ps)
-        return (bt[b, jnp.minimum(fr[b] + j, nb - 1)], 0, 0)
+    def page_map(j):                                   # (P, Hkv, ps, D)
+        return lambda b, i, bt, fr, nr: (bt[b, page(j, b, i, fr, nr)], 0, 0,
+                                         0)
 
-    def q_map(b, j, bt, fr):
+    def row_map(j):                           # (P, 1, ps) / (P, Hkv, ps)
+        return lambda b, i, bt, fr, nr: (bt[b, page(j, b, i, fr, nr)], 0, 0)
+
+    def q_map(b, i, bt, fr, nr):
         return (b, 0, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, Hkv, rows, D), q_map),
-        pl.BlockSpec((1, rows, 1), lambda b, j, bt, fr: (b, 0, 0)),
-        pl.BlockSpec((1, Hkv, ps, D), page_map),
-        pl.BlockSpec((1, Hkv, ps, D), page_map),
-        pl.BlockSpec((1, 1, ps), row_map),
-    ]
-    operands = [q_, qp_, k_pages, v_pages, pos_pages[:, None, :]]
+    def per_page(shape, index_map):
+        return [pl.BlockSpec(shape, index_map(j)) for j in range(ppb)]
+
+    def qp_map(b, i, bt, fr, nr):
+        return (b, 0, 0)
+
+    in_specs = ([pl.BlockSpec((1, Hkv, rows, D), q_map),
+                 pl.BlockSpec((1, rows, 1), qp_map)]
+                + per_page((1, Hkv, ps, D), page_map)
+                + per_page((1, Hkv, ps, D), page_map)
+                + per_page((1, 1, ps), row_map))
+    operands = ([q_, qp_] + [k_pages] * ppb + [v_pages] * ppb
+                + [pos_pages[:, None, :]] * ppb)
     if quant:
-        in_specs += [pl.BlockSpec((1, Hkv, ps), row_map),
-                     pl.BlockSpec((1, Hkv, ps), row_map)]
-        operands += [k_scale_pages, v_scale_pages]
+        in_specs += (per_page((1, Hkv, ps), row_map)
+                     + per_page((1, Hkv, ps), row_map))
+        operands += [k_scale_pages] * ppb + [v_scale_pages] * ppb
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, nb),
+        num_scalar_prefetch=3,
+        grid=(B, -(-nb // ppb)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Hkv, rows, D), q_map),
         scratch_shapes=_scratch(Hkv, rows, D),
     )
+    vmem = _paged_vmem_bytes(Hkv=Hkv, rows=rows, D=D, ps=ps, ppb=ppb,
+                             q_dtype=q.dtype, kv_dtype=k_pages.dtype,
+                             quant=quant)
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, nb=nb, window=window, cap=attn_cap,
-                          scale=scale, quant=quant),
+        functools.partial(_paged_kernel, ppb=ppb, ps=ps, window=window,
+                          cap=attn_cap, scale=scale, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + vmem // 4 + (4 << 20)),
         interpret=interpret,
         name="paged_prefill_attention",
-    )(block_tables.astype(jnp.int32), first, *operands)
+    )(block_tables.astype(jnp.int32), first, n, *operands)
     return _unfold_heads(out, k, G)
 
 

@@ -26,7 +26,9 @@
 * **step shape** -- ``step_slots`` / ``step_tokens`` and their ratio
   ``step_fill`` (real tokens over the ``rows x width`` slots dispatched:
   padding of partial chunks and of decode lanes riding a chunk-wide
-  step), ``pages_in_use`` / ``peak_pages``, ``requeues``.  The step loop
+  step), ``pages_in_use`` / ``peak_pages``, ``requeues``,
+  ``kv_pages_walked`` / ``kv_pages_table`` (the share of the block table
+  the paged attention kernel walks).  The step loop
   fills them where it writes the same counts as the args of its
   ``serve.step`` profiler span (``serve/step_loop.py`` ``SPANS``).
 
@@ -92,6 +94,10 @@ class ServeStats:
     # real prompt + decode tokens in them
     step_slots: int = 0
     step_tokens: int = 0
+    # chunked: block-table pages the paged attention kernel walks per layer
+    # (summed over lanes and steps), and the rows x table width it spans
+    kv_pages_walked: int = 0
+    kv_pages_table: int = 0
     # ---- speculative decode (run(speculative=True)) ----
     spec_steps: int = 0             # verify steps with >= 1 speculating lane
     spec_lane_steps: int = 0        # per-lane verify events (lane x step)

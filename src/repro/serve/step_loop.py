@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.kernels.attention import live_pages
 from repro.serve import paged_kv
 from repro.serve.frontend import FrontEnd
 from repro.serve.scheduler import Request, Scheduler
@@ -69,7 +70,9 @@ SPANS = (
     # one StepLoop.step; args are its dispatch's counts: step, rows, width,
     # prompt_tokens, decode_tokens, slots (rows x width; 0 when every
     # planned slot was preempted and nothing ran), fresh_pages,
-    # pages_in_use, requeued -- the numbers ServeStats adds up
+    # pages_in_use, requeued, kv_pages (block-table pages the paged
+    # attention kernel walks per layer, summed over lanes) and
+    # kv_pages_table (rows x table width) -- the numbers ServeStats adds up
     "serve.step",
     "serve.admit",     # child of serve.step: first-chunk admissions
     "serve.plan",      # child of serve.step: Scheduler.plan_step
@@ -285,11 +288,23 @@ class StepLoop:
         prompt = sum(plan["chunked"].values())
         decode = sum(plan["spec"].get(i, 1) for i in plan["decode"])
         in_use = self.num_pages - 1 - sched.allocator.n_free
+        # the attention walk of a full-attention layer (of a windowed one
+        # when every layer shares the window): the kernel's own live_pages
+        kv_pages = kv_table = 0
+        if width:
+            nb = sched.tables.blocks_per_seq
+            _, walked = live_pages(plan["positions"][:, :width],
+                                   page_size=self.page_size, n_blocks=nb,
+                                   window=self.reclaim)
+            kv_pages, kv_table = int(walked.sum()), rows * nb
         span.set_metadata(
             step=stats.steps, rows=rows, width=width, prompt_tokens=prompt,
             decode_tokens=decode, slots=rows * width,
             fresh_pages=fresh_pages, pages_in_use=in_use,
-            requeued=len(plan["requeued"]))
+            requeued=len(plan["requeued"]), kv_pages=kv_pages,
+            kv_pages_table=kv_table)
+        stats.kv_pages_walked += kv_pages
+        stats.kv_pages_table += kv_table
         stats.requeues += len(plan["requeued"])
         stats.chunk_prefill_tokens += prompt
         stats.step_slots += rows * width

@@ -279,6 +279,45 @@ def test_paged_prefill_kernel_int8_pages_match_oracle(seed, window, k):
                                    err_msg=f"row {i}", **TOL)
 
 
+# (lens, k, window, cap, pool): page_size 4 -> 32 pages (128 slots) to a
+# compute block; the longest lane (300 positions, 75 pages) makes nb 76,
+# not a multiple of 32, so lanes walk one, two or three blocks
+LONG_WALKS = {
+    "decode-int8": ([150, 0, 50, 300], 1, None, None, "int8"),
+    "decode-bf16-window-softcap": ([300, 93, 0], 1, 70, 30.0, "bf16"),
+    "chunk-f32": ([300, 129, 0, 7], 5, None, None, "f32"),
+    "chunk-bf16-window-softcap": ([250, 0, 20, 300], 6, 70, 30.0, "bf16"),
+    "chunk-int8-window": ([250, 300, 40, 0], 8, 70, None, "int8"),
+}
+
+
+@pytest.mark.parametrize("case", list(LONG_WALKS), ids=list(LONG_WALKS))
+def test_paged_kernel_multi_block_walk_matches_oracle(case):
+    """Walks of several 32-page compute blocks == dense gather + oracle:
+    contexts ending mid-block and shorter than one block, a last block cut
+    by the table's end, windows whose first live page falls mid-block,
+    softcap, f32 / bf16 / int8 pools, decode and chunk tiles.  Idle lanes
+    walk nothing and return exact zeros."""
+    lens, k, window, cap, pool = LONG_WALKS[case]
+    rng = np.random.default_rng(sorted(LONG_WALKS).index(case))
+    q, pools, bt, q_pos = _paged_chunk_pool(
+        rng, lens, k, 4, 2, 2, 8, kv_bits=8 if pool == "int8" else None)
+    if pool == "bf16":
+        pools = dict(pools, k=pools["k"].astype(jnp.bfloat16),
+                     v=pools["v"].astype(jnp.bfloat16))
+    nb = bt.shape[1]
+    assert nb > 32 and nb % 32
+    ref = np.asarray(_run_paged(q, pools, bt, q_pos, "ref", window=window,
+                                attn_cap=cap))
+    got = np.asarray(_run_paged(q, pools, bt, q_pos, "pallas",
+                                window=window, attn_cap=cap))
+    for i, s in enumerate(lens):           # real columns (see above)
+        np.testing.assert_allclose(got[i, :min(k, s)], ref[i, :min(k, s)],
+                                   err_msg=f"row {i}", **TOL)
+    idle = [i for i, s in enumerate(lens) if not s]
+    assert idle and np.all(got[idle] == 0.0)
+
+
 def test_paged_prefill_single_page_single_tile_bitwise():
     """One page and one q tile degenerate to the oracle's single-shot
     softmax: bit equality, like the flash kernel's single-tile case."""

@@ -5,7 +5,8 @@
   from a real ``jax.profiler`` trace: one ``serve.step`` per dispatched
   step, its args summing to the ``ServeStats`` counters, every child
   inside its parent, every device enqueue inside the phase that makes it;
-* the step-shape counters at exact values for a known plan;
+* the step-shape counters at exact values for a known plan, and the
+  paged kernel's walk recomputed from each step's positions;
 * ``engine.stats`` outlives a session ended by an exception;
 * every weight store's dequant carries the ``dequant`` scope into the
   op metadata of the lowered ``model_step``.
@@ -21,6 +22,7 @@ from repro.configs import ARCHS
 from repro.models import LM
 from repro.quant.policy import QuantPolicy
 from repro.serve import FrontEnd, ServeEngine, paged_kv
+from repro.models.transformer import POS_SENTINEL
 from repro.serve.step_loop import SPANS
 
 KEY = jax.random.PRNGKey(0)
@@ -104,6 +106,8 @@ def test_step_spans_add_up_to_serve_stats(tmp_path, serve_kw):
     assert sum(a["requeued"] for a in steps) == stats.requeues
     assert all(a["slots"] == a["rows"] * a["width"] for a in steps)
     assert max(a["pages_in_use"] for a in steps) == stats.peak_pages
+    assert sum(a["kv_pages"] for a in steps) == stats.kv_pages_walked > 0
+    assert sum(a["kv_pages_table"] for a in steps) == stats.kv_pages_table
     def inside(name, parents):
         iv = [(s, e) for n, s, e, _ in spans if n in parents]
         return all(any(ps <= s and e <= pe for ps, pe in iv)
@@ -131,6 +135,44 @@ def test_step_shape_counters_for_a_known_plan():
     assert (stats.step_slots, stats.step_tokens) == (20, 7)
     assert stats.step_fill == pytest.approx(7 / 20)
     assert stats.pages_in_use == 0 and stats.peak_pages == 2
+    # the kernel walks page 0 (positions 0..3), then pages 0-1 (4, 5, 6);
+    # the idle lane walks nothing.  The table is 2 rows x 8 blocks (32 / 4)
+    assert stats.kv_pages_walked == 1 + 2 + 2 + 2
+    assert stats.kv_pages_table == 4 * 2 * 8
+
+
+def test_kv_pages_are_the_walk_of_each_dispatched_step(tmp_path,
+                                                       monkeypatch):
+    """Each ``serve.step``'s ``kv_pages`` is the pages the paged kernel
+    walks, recomputed from the positions ``model_step`` was given: a lane
+    walks its table up to the page of its highest real position, an idle
+    lane nothing."""
+    cfg, eng = _engine()
+    calls = []
+    step = eng._model_step
+
+    def spy(params, tokens, positions, slot_map, cache, tables, *a, **kw):
+        calls.append((np.asarray(positions), np.asarray(tables).shape[1]))
+        return step(params, tokens, positions, slot_map, cache, tables, *a,
+                    **kw)
+
+    monkeypatch.setattr(eng, "_model_step", spy)
+    with jax.profiler.trace(str(tmp_path)):
+        stats = eng.run(_requests(cfg.vocab, MIXED), page_size=4,
+                        max_slots=4, chunk_tokens=4)["stats"]
+    prof = jax.profiler.ProfileData.from_file(
+        str(next(pathlib.Path(tmp_path).rglob("*.xplane.pb"))))
+    steps = sorted((dict(e.stats) for p in prof.planes
+                    if p.name.startswith("/host:") for line in p.lines
+                    for e in line.events if e.name == "serve.step"),
+                   key=lambda a: a["step"])
+    assert len(steps) == len(calls) == stats.steps
+    for args, (pos, nb) in zip(steps, calls):
+        walked = sum(int(row[row != POS_SENTINEL].max()) // 4 + 1
+                     for row in pos if (row != POS_SENTINEL).any())
+        assert args["kv_pages"] == walked
+        assert args["kv_pages_table"] == pos.shape[0] * nb
+    assert stats.kv_pages_walked == sum(a["kv_pages"] for a in steps)
 
 
 class _PumpRaises(FrontEnd):

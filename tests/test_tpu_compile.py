@@ -1,5 +1,6 @@
 """The serve path's attention kernels compile for a TPU v5e at gemma2-2b's
-published widths (Hq 8, Hkv 4, head_dim 256, page_size 16).
+published widths (Hq 8, Hkv 4, head_dim 256, page_size 16), and the paged
+kernel at the shapes the benchmark's phi4-mini cell serves.
 
 Ahead-of-time compiles against a *described* ``v5e:2x2`` topology with
 ``interpret=False``: nothing runs, but the chip's compiler checks what the
@@ -86,5 +87,28 @@ def test_paged_attention_compiles(k, kv_bits, one_chip, no_persistent_cache):
         return paged_prefill_attention(
             q, kp, vp, pos, bt, q_pos=qp, window=WINDOW, attn_cap=CAP,
             k_scale_pages=ks, v_scale_pages=vs, interpret=False)
+
+    _compile(fn, shapes, one_chip)
+
+
+@pytest.mark.parametrize("k", [256, 1], ids=["chunk", "decode"])
+def test_paged_attention_compiles_at_phi4_mini_cell(k, one_chip,
+                                                    no_persistent_cache):
+    """phi4-mini's widths (Hq 24, Hkv 8, head_dim 128) at the prefill-long
+    cell's shapes: 4 lanes x 514 blocks of 16 positions, int8 pools with
+    scale pages, no window.  The 256-token chunk makes 768 q rows per KV
+    head, the kernel's largest VMEM footprint in the benchmark."""
+    B, hq, hkv, hd, ps, nb = 4, 24, 8, 128, 16, 514
+    P = B * nb + 1
+    shapes = [((B, k, hq, hd), jnp.bfloat16),
+              ((P, hkv, ps, hd), jnp.int8), ((P, hkv, ps, hd), jnp.int8),
+              ((P, ps), jnp.int32), ((B, nb), jnp.int32),
+              ((B, k), jnp.int32),
+              ((P, hkv, ps), jnp.float32), ((P, hkv, ps), jnp.float32)]
+
+    def fn(q, kp, vp, pos, bt, qp, ks, vs):
+        return paged_prefill_attention(
+            q, kp, vp, pos, bt, q_pos=qp, k_scale_pages=ks,
+            v_scale_pages=vs, interpret=False)
 
     _compile(fn, shapes, one_chip)
