@@ -2,7 +2,8 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests the window finished -- the longest of them and others drawn
-from the seed -- runs through the plain reference (``bench.reference``):
+from the seed -- runs through the plain reference (the ``Reference`` of
+the configuration's architecture module, over ``bench.reference``):
 each prompt with its served tokens, packed several to a row of one
 fixed length per cell, so one compiled pass serves every run.  For every
 served token
@@ -23,8 +24,6 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 import numpy as np
-
-from bench.reference.model import Reference
 
 PAD_TO = 128
 ROW_BLOCK = 256
@@ -115,20 +114,20 @@ def _gaps(ref, h_rows, chosen):
     return np.concatenate(out)[:n]
 
 
-def served_gap(cfg: dict, seed: int, sample, length: int) -> dict:
+def served_gap(arch, cfg: dict, seed: int, sample, length: int) -> dict:
     """Widest reference-logit gap of the sample's served tokens, the
-    reference running over rows of ``length`` slots."""
-    ref = Reference(cfg, seed)
+    reference of ``arch`` running over rows of ``length`` slots."""
+    ref = arch.Reference(cfg, seed)
     h, toks = _served_hidden(ref, served_rows(sample, length))
     gaps = _gaps(ref, h, toks)
     return {"served_gap": float(gaps.max()), "mean_gap": float(gaps.mean()),
             "tokens": int(len(toks)), "requests": len(sample)}
 
 
-def control_gap(cfg: dict, seed: int, sample, length: int) -> dict:
+def control_gap(arch, cfg: dict, seed: int, sample, length: int) -> dict:
     """Widest gap of the tokens the fp8 control puts first, and the
     program's own served gap, at the same positions."""
-    ref = Reference(cfg, seed)
+    ref = arch.Reference(cfg, seed)
     rows = served_rows(sample, length)
     h, toks = _served_hidden(ref, rows)
     hc, _ = _served_hidden(ref, rows, "fp8")

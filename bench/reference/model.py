@@ -1,6 +1,8 @@
-"""Plain float32 forward of a dense decoder: RMSNorm, RoPE, grouped-query
-attention, SwiGLU -- the architecture of phi4-mini-3.8b and internlm2-20b
-as published, over the weights the configuration's policy stores.
+"""Plain float32 forward pieces of the decoders the benchmark serves:
+RMSNorm, RoPE, grouped-query attention (full or in a sliding window),
+SwiGLU, and :class:`Decoder`, the layer loop an architecture's
+``Reference`` (``arch/<architecture>.py``) fills in, over the weights the
+configuration's policy stores.
 
 It imports nothing of the program and takes nothing the program made.
 Weights come from the benchmark's seeded generator (``bench.weights``,
@@ -95,11 +97,12 @@ def rope(x, theta, pos):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(q, k, v, seg):
+def attention(q, k, v, seg, window=None):
     """Causal GQA within each segment of a row (``seg`` (n, L): the
     sequence each slot belongs to, sequences contiguous), q head h
-    reading kv head h // (Hq / Hkv), in blocks of queries.
-    q: (n, L, Hq, D); k, v: (n, L, Hkv, D)."""
+    reading kv head h // (Hq / Hkv), in blocks of queries.  With a
+    ``window``, a key ``window`` or more positions behind the query is
+    masked.  q: (n, L, Hq, D); k, v: (n, L, Hkv, D)."""
     n, L, Hq, D = q.shape
     G = Hq // k.shape[2]
     k = jnp.repeat(k, G, axis=2)
@@ -111,15 +114,17 @@ def attention(q, k, v, seg):
         qi = s + jnp.arange(qb.shape[1])[:, None]
         ok = (jnp.arange(L)[None, :] <= qi)[None] & (
             seg[:, s:s + Q_BLOCK, None] == seg[:, None, :])
+        if window is not None:
+            ok &= (jnp.arange(L)[None, :] > qi - window)[None]
         sc = jnp.where(ok[:, None], sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         outs.append(jnp.einsum("nhqk,nkhd->nqhd", p, v, precision=HI))
     return jnp.concatenate(outs, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "mode"))
-def _layer(x, pos, seg, w, cfg_items, mode):
-    c = dict(cfg_items)
+def self_attention(x, pos, seg, w, c, mode, window=None):
+    """``x`` plus the attention sublayer of weights ``w`` (``wq``, ``wk``,
+    ``wv``, ``wo``) under the layer items ``c`` (:func:`layer_items`)."""
     n, L, d = x.shape
     hq, hkv, hd = c["num_attention_heads"], c["num_key_value_heads"], \
         c["head_dim"]
@@ -128,51 +133,62 @@ def _layer(x, pos, seg, w, cfg_items, mode):
     k = matmul(h, w["wk"], mode).reshape(n, L, hkv, hd)
     v = matmul(h, w["wv"], mode).reshape(n, L, hkv, hd)
     q, k = rope(q, c["rope_theta"], pos), rope(k, c["rope_theta"], pos)
-    o = attention(q, k, v, seg).reshape(n, L, hq * hd)
-    x = x + matmul(o, w["wo"], mode)
+    o = attention(q, k, v, seg, window).reshape(n, L, hq * hd)
+    return x + matmul(o, w["wo"], mode)
+
+
+def swiglu(x, w, c, mode):
+    """``x`` plus the SwiGLU sublayer of weights ``w`` (``wg``, ``wu``,
+    ``wd``)."""
     h = quantize_tokens(rmsnorm(x, c["rms_norm_eps"]), c["act_bits"])
     g = matmul(h, w["wg"], mode)
     u = matmul(h, w["wu"], mode)
     return x + matmul(jax.nn.silu(g) * u, w["wd"], mode)
 
 
-def _cfg_items(cfg):
+@functools.partial(jax.jit, static_argnames=("items", "mode", "window"))
+def dense_layer(x, pos, seg, w, items, mode, window=None):
+    """One dense decoder layer: attention, then SwiGLU."""
+    c = dict(items)
+    return swiglu(self_attention(x, pos, seg, w, c, mode, window), w, c,
+                  mode)
+
+
+def layer_items(cfg):
+    """The sizes a layer function needs, hashable (a jit static)."""
     keys = ("num_attention_heads", "num_key_value_heads", "head_dim",
             "rms_norm_eps", "rope_theta")
     return tuple((k, cfg[k]) for k in keys) + (
         ("act_bits", float(cfg["policy"]["act_bits"])),)
 
 
-class Reference:
-    """The reference of one configuration and seed."""
+class Decoder:
+    """The reference of one configuration and seed, less what each
+    layer is: an architecture's ``Reference`` subclasses it with
+    ``layer_weights(l)`` and ``layer(l, x, pos, seg, w, mode)`` for layer
+    ``l``.  ``shapes`` ({role: (K, N)}), ``role_ids`` and ``bits`` (per-
+    group QBNs, the unembed's too) are the architecture's; ``stack``
+    {role: layers} says over how many layers each role's channel maxima
+    are taken (its stack in the stored tree)."""
 
-    def __init__(self, cfg: dict, seed: int):
+    def __init__(self, cfg: dict, seed: int, shapes: dict, role_ids: dict,
+                 bits: dict, stack: dict):
         self.cfg = cfg
         self.key = weights.base_key(seed)
         self.dims = weights.dims(cfg)
-        self.shapes = weights.layer_shapes(cfg)
-        bits = weights.policy_bits(cfg)
+        self.shapes = shapes
+        self.role_ids = role_ids
         self.bits = {r: jnp.asarray(weights.channel_bits(bits[r], n))
-                     for r, (_, n) in list(self.shapes.items()) +
+                     for r, (_, n) in list(shapes.items()) +
                      [("unembed", (0, self.dims["vocab_padded"]))]}
-        self.amax = self._stack_amax()
+        self.amax = {role: stack_amax(self.key, role_ids[role], stack[role],
+                                      shape)
+                     for role, shape in shapes.items()}
 
-    def _stack_amax(self):
-        R = self.dims["layers"]
-        out = {}
-        for role, shape in self.shapes.items():
-            f = jax.jit(lambda key, r, role=role, shape=shape:
-                        weights.channel_amax(
-                            weights.layer_weight(key, role, r, shape)))
-            a = f(self.key, 0)
-            for r in range(1, R):
-                a = jnp.maximum(a, f(self.key, r))
-            out[role] = a
-        return out
-
-    def layer_weights(self, r: int) -> dict:
-        return _layer_weights(self.key, r, self.amax, self.bits,
-                              tuple(self.shapes.items()))
+    def quantized(self, r: int, roles) -> dict:
+        """Layer ``r`` of each of ``roles``' stacks, on its grid."""
+        return _layer_weights(self.key, r, self.amax, self.bits, tuple(
+            (role, self.role_ids[role], self.shapes[role]) for role in roles))
 
     def hidden(self, tokens: np.ndarray, mode: str = "f32"):
         """Final-normed hidden states (n, L, d) of a padded token batch,
@@ -188,14 +204,11 @@ class Reference:
         sequences, each with its own positions and segment id.  Each
         layer's weights are made once for all batches."""
         d, vp = self.dims["d"], self.dims["vocab_padded"]
-        emb = jax.jit(lambda key, t: jnp.take(
-            weights.embed(key, vp, d), t, axis=0).astype(jnp.float32))
-        xs = [emb(self.key, jnp.asarray(t)) for t, _, _ in rows]
+        xs = [_embed(self.key, jnp.asarray(t), d, vp) for t, _, _ in rows]
         aux = [(jnp.asarray(p), jnp.asarray(g)) for _, p, g in rows]
-        items = _cfg_items(self.cfg)
         for r in range(self.dims["layers"]):
             w = self.layer_weights(r)
-            xs = [_layer(x, p, g, w, items, mode)
+            xs = [self.layer(r, x, p, g, w, mode)
                   for x, (p, g) in zip(xs, aux)]
         return [rmsnorm(x, self.cfg["rms_norm_eps"]) for x in xs]
 
@@ -205,11 +218,30 @@ class Reference:
                        self.dims["vocab_padded"], self.dims["vocab"], mode)
 
 
+def stack_amax(key, role_id: int, layers: int, shape):
+    """Channel maxima of one role's weights over its ``layers``."""
+    a = _channel_amax(key, 0, role_id, tuple(shape))
+    for r in range(1, layers):
+        a = jnp.maximum(a, _channel_amax(key, r, role_id, tuple(shape)))
+    return a
+
+
+@functools.partial(jax.jit, static_argnames=("role_id", "shape"))
+def _channel_amax(key, r, role_id, shape):
+    return weights.channel_amax(weights.layer_weight(key, role_id, r, shape))
+
+
+@functools.partial(jax.jit, static_argnames=("d", "vp"))
+def _embed(key, tokens, d, vp):
+    return jnp.take(weights.embed(key, vp, d), tokens,
+                    axis=0).astype(jnp.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("shapes",))
 def _layer_weights(key, r, amax, bits, shapes):
     return {role: quantize_channels(weights.untie(
-        weights.layer_weight(key, role, r, shape), bits[role], amax[role]),
-        bits[role], amax[role]) for role, shape in shapes}
+        weights.layer_weight(key, rid, r, shape), bits[role], amax[role]),
+        bits[role], amax[role]) for role, rid, shape in shapes}
 
 
 @functools.partial(jax.jit, static_argnames=("d", "vp", "vocab", "mode"))

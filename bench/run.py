@@ -104,9 +104,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     comparison: the tokens it puts first at the served positions are
     judged against the same limit (``bench/control.py``)."""
     from bench import correctness, e2e, serving, spec, trace_reduce
-    cfg, traffic = cell["config"], cell["traffic"]
+    cfg, traffic, arch = cell["config"], cell["traffic"], cell["arch"]
     log(f"cell {cell['workload']['name']} seed {seed}: build + pack weights")
-    eng = serving.build_engine(cfg, traffic, seed)
+    eng = serving.build_engine(arch, cfg, traffic, seed)
     weight_bytes = eng.weight_hbm_bytes()
     log(f"weights stored: {weight_bytes}; warm-up")
     serving.warm_up(eng, cfg, traffic, cfg["vocab_size"])
@@ -148,7 +148,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
             trace_reduce.load(trace_dir).planes)
         shutil.rmtree(trace_dir, ignore_errors=True)
         ctx = types.SimpleNamespace(
-            trace=red, steps=serving.step_entries(win), cfg=cfg,
+            trace=red, steps=serving.step_entries(win), cfg=cfg, arch=arch,
             peak=peaks(device["kind"]),
             weight_bytes=weight_bytes["packed"] + weight_bytes["int8"],
             reqs=reqs, t0=t0, t1=t1, admitted_s=win["admitted_s"])
@@ -167,12 +167,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     if not sample:
         gap = mean = None
     elif control:
-        res = correctness.control_gap(cfg, seed, sample, length)
+        res = correctness.control_gap(arch, cfg, seed, sample, length)
         gap, mean = res["control_gap"], res["control_mean_gap"]
         extra = {"program_gap": res["served_gap"],
                  "program_mean_gap": res["mean_gap"]}
     else:
-        res = correctness.served_gap(cfg, seed, sample, length)
+        res = correctness.served_gap(arch, cfg, seed, sample, length)
         gap, mean = res["served_gap"], res["mean_gap"]
     log(f"reference: gap {gap}, mean gap {mean} over {len(sample)} "
         f"requests, {sum(r['n_new'] for r in sample)} served tokens {extra}")

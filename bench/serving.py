@@ -22,10 +22,8 @@ import numpy as np
 
 from bench import traffic as traffic_mod
 from bench import weights
-from repro.configs.base import dense
 from repro.kernels.pack import PackedWeight
 from repro.models import LM
-from repro.models.api import LMConfig
 from repro.quant.apply import apply_policy_packed
 from repro.quant.policy import QuantMode, QuantPolicy
 from repro.serve import FrontEnd, ServeEngine, paged_kv
@@ -40,24 +38,14 @@ class WindowClosed(Exception):
     """Raised from the front end's pump when the window has closed."""
 
 
-def lm_config(cfg: dict):
-    return LMConfig(
-        name=cfg["name"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        n_layers=cfg["num_hidden_layers"], pattern=(dense(),),
-        head_dim=cfg["head_dim"], rope_theta=cfg["rope_theta"],
-        norm_eps=cfg["rms_norm_eps"])
-
-
-def program_policy(model, cfg: dict):
+def program_policy(arch, model, cfg: dict):
     """The configuration's policy as the program's ``QuantPolicy`` over
-    its graph, plus that graph."""
+    its graph, plus that graph; ``arch`` (the configuration's
+    architecture module) maps each graph site to its role."""
     graph = model.graph(seq_len=1, batch=1,
                         max_groups=cfg["policy"]["max_groups"])
-    bits = weights.policy_bits(cfg)
-    by_site = {l.name: l.name.split(".")[-1] for l in graph.layers}
+    bits = arch.policy_bits(cfg)
+    by_site = {l.name: arch.site_role(l.name) for l in graph.layers}
     if sorted(by_site.values()) != sorted(bits):
         raise RuntimeError(f"program graph sites {sorted(by_site)} do not "
                            f"match the configuration's {sorted(bits)}")
@@ -67,18 +55,20 @@ def program_policy(model, cfg: dict):
                        act_bits={n: act for n in by_site}), graph
 
 
-def build_engine(cfg: dict, traffic: dict, seed: int):
-    """Random bf16 weights from ``seed``, packed by the program under the
-    configuration's policy in one jitted call, handed to ``ServeEngine``.
+def build_engine(arch, cfg: dict, traffic: dict, seed: int):
+    """Random bf16 weights from ``seed`` in the layout of ``arch`` (the
+    configuration's architecture module), packed by the program under
+    the configuration's policy in one jitted call, handed to
+    ``ServeEngine``.
 
     The engine has no entry for an already-packed tree: it is given the
     tree with no policy, and the policy's activation QBNs are set on it
     as ``ServeEngine(policy=...)`` would have set them."""
-    model = LM(lm_config(cfg))
-    policy, graph = program_policy(model, cfg)
+    model = LM(arch.lm_config(cfg))
+    policy, graph = program_policy(arch, model, cfg)
     want = jax.eval_shape(lambda k: model.init(k, dtype=jnp.bfloat16),
                           jax.random.PRNGKey(0))
-    got = jax.eval_shape(lambda k: weights.program_tree(k, cfg),
+    got = jax.eval_shape(lambda k: arch.program_tree(k, cfg),
                          jax.random.PRNGKey(0))
     if jax.tree.structure(want) != jax.tree.structure(got) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype) for a, b in
@@ -86,7 +76,7 @@ def build_engine(cfg: dict, traffic: dict, seed: int):
         raise RuntimeError("the program's parameter layout changed: "
                            f"{want} vs the benchmark's {got}")
     build = jax.jit(lambda k: apply_policy_packed(
-        weights.program_tree(k, cfg), graph, policy))
+        arch.program_tree(k, cfg), graph, policy))
     params = jax.block_until_ready(build(weights.base_key(seed)))
     buckets = {name for leaf in jax.tree.leaves(
         params, is_leaf=lambda x: isinstance(x, PackedWeight))

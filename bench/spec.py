@@ -5,6 +5,9 @@ per-layer metric or one cell's correctness limits is a file of its own,
 found by name, so a new cell or metric is new files plus new entries:
 
 * ``configs/<config>.json``  -- via the ``file`` of the config entry;
+* ``arch/<architecture>.py`` -- the architecture the configuration names
+  under ``architecture``: everything the harness does that depends on
+  the model's layers (see ``arch/dense.py``);
 * ``traffic/<traffic>.json`` -- the mix the general generator reads;
 * ``metrics/<metric>.py``    -- the reader of one per-layer metric; a
   metric named ``<quantity>.<suffix>`` falls back to ``<quantity>.py``
@@ -13,6 +16,7 @@ found by name, so a new cell or metric is new files plus new entries:
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import pathlib
@@ -46,6 +50,9 @@ def cell(bm: dict, workload: str, root: pathlib.Path = ROOT) -> dict:
     c = _by_name(bm["configs"], w["config"], "config")
     bench = root / "bench"
     cfg = json.loads((root / c["file"]).read_text())
+    if "architecture" not in cfg:
+        raise SpecError(f"{c['file']} names no architecture")
+    arch = architecture(cfg["architecture"], bench / "arch")
     traffic_path = bench / "traffic" / f"{w['traffic']}.json"
     if not traffic_path.is_file():
         raise SpecError(f"no traffic file {traffic_path}")
@@ -59,7 +66,7 @@ def cell(bm: dict, workload: str, root: pathlib.Path = ROOT) -> dict:
            if workload in m.get("workloads", [workload])]
     layer = [m for m in bm["per_layer"]
              if workload in m.get("workloads", [workload])]
-    return {"workload": w, "config": cfg,
+    return {"workload": w, "config": cfg, "arch": arch,
             "traffic": json.loads(traffic_path.read_text()),
             "limits": limits,
             "end_to_end": e2e, "per_layer": layer}
@@ -73,8 +80,23 @@ def metric_reader(name: str, bench: pathlib.Path = BENCH_DIR):
         path = metrics / f"{name.split('.')[0]}.py"
     if not path.is_file():
         raise SpecError(f"no reader for metric {name!r} in {metrics}")
+    return _load(path, "bench_metric").read
+
+
+def architecture(name: str, arch_dir: pathlib.Path = BENCH_DIR / "arch"):
+    """The module of one architecture, ``<arch_dir>/<name>.py``."""
+    path = arch_dir / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"no architecture {name!r} in {arch_dir}")
+    return _load(path, "bench_arch")
+
+
+@functools.cache
+def _load(path: pathlib.Path, prefix: str):
+    """The module at ``path``, loaded once per process: the jitted
+    functions of an architecture then compile once."""
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{path.stem.replace('.', '_')}", path)
+        f"{prefix}_{path.stem.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod

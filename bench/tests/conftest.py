@@ -18,6 +18,25 @@ def fixture_json(name):
     return json.loads((FIXTURES / name).read_text())
 
 
+# each test configuration with the directory of the architecture it
+# names: the benchmark's own, where ``spec.cell`` finds it, or the
+# fixtures for a test architecture that reaches the harness as new files
+ARCH_DIRS = {"smoke-dense.json": ROOT / "bench" / "arch",
+             "smoke-window-mixed.json": FIXTURES}
+
+
+def arch_of(config):
+    """The architecture module test configuration ``config`` names."""
+    from bench import spec
+    return spec.architecture(fixture_json(config)["architecture"],
+                             ARCH_DIRS[config])
+
+
 @pytest.fixture
 def smoke_cfg():
     return fixture_json("smoke-dense.json")
+
+
+@pytest.fixture
+def dense():
+    return arch_of("smoke-dense.json")

@@ -9,25 +9,30 @@ import numpy as np
 import pytest
 
 from bench import correctness, serving, weights
-from bench.reference.model import Reference
-from bench.tests.conftest import ROOT, fixture_json
+from bench.tests.conftest import ARCH_DIRS, ROOT, arch_of, fixture_json
 from bench.tests.smoke import smoke_run
 
 sys.path.insert(0, str(ROOT / "bench"))
 
 LIMIT = fixture_json("smoke.limits.json")["served_gap"]
+# the dense architecture, and a test one that mixes window and full
+# attention and reaches the harness only as new files
+CONFIGS = list(ARCH_DIRS)
 
 
-def test_reference_matches_the_programs_f32_forward(smoke_cfg):
+@pytest.mark.parametrize("config", CONFIGS)
+def test_reference_matches_the_programs_f32_forward(config):
     """Same weights, same QBNs, both in float32 at highest precision: the
     program's ``LM.apply`` and the reference differ by rounding alone."""
     from repro.kernels.pack import PackedWeight
     from repro.models import LM
     from repro.quant.apply import apply_policy_packed
-    model = LM(serving.lm_config(smoke_cfg))
-    policy, graph = serving.program_policy(model, smoke_cfg)
+    smoke_cfg = fixture_json(config)
+    arch = arch_of(config)
+    model = LM(arch.lm_config(smoke_cfg))
+    policy, graph = serving.program_policy(arch, model, smoke_cfg)
     params = apply_policy_packed(
-        weights.program_tree(weights.base_key(7), smoke_cfg), graph, policy)
+        arch.program_tree(weights.base_key(7), smoke_cfg), graph, policy)
     is_pw = lambda x: isinstance(x, PackedWeight)
     params = jax.tree.map(
         lambda x: PackedWeight(x.parts, x.k, x.n, x.buckets, "float32")
@@ -37,14 +42,15 @@ def test_reference_matches_the_programs_f32_forward(smoke_cfg):
     with jax.default_matmul_precision("highest"):
         lg, _ = model.apply(params, {"tokens": jnp.asarray(toks)},
                             act_bits=act)
-    ref = Reference(smoke_cfg, 7)
+    ref = arch.Reference(smoke_cfg, 7)
     h = ref.hidden(toks)
     rl = np.stack([np.asarray(ref.logits(h[i])) for i in range(2)])
     assert np.abs(np.asarray(lg)[..., :256] - rl).max() < 1e-3
 
 
-def test_sound_run_is_correct():
-    out = smoke_run(2 ** 35 + 3)
+@pytest.mark.parametrize("config", CONFIGS)
+def test_sound_run_is_correct(config):
+    out = smoke_run(2 ** 35 + 3, config=config)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
 
@@ -86,8 +92,8 @@ def _rows_mixed(monkeypatch):
 def _token_altered(monkeypatch):
     orig = serving.build_engine
 
-    def build(cfg, traffic, seed):
-        eng = orig(cfg, traffic, seed)
+    def build(arch, cfg, traffic, seed):
+        eng = orig(arch, cfg, traffic, seed)
         sample = eng._sample_span
         eng._sample_span = lambda *a: (
             lambda t, k: ((t + 1) % cfg["vocab_size"], k))(*sample(*a))
@@ -103,7 +109,8 @@ def test_fault_in_the_timed_path_is_not_correct(fault, monkeypatch):
     assert not out["correct"], out["checks"]
 
 
-def test_packed_rows_serve_each_token_once_and_change_nothing(smoke_cfg):
+def test_packed_rows_serve_each_token_once_and_change_nothing(smoke_cfg,
+                                                             dense):
     """Sequences packed into fixed rows, longest first, each attending
     within itself only: every served token is read once, and each one's
     reference gap is what the sequence alone gives."""
@@ -125,7 +132,7 @@ def test_packed_rows_serve_each_token_once_and_change_nothing(smoke_cfg):
         assert (tok[0, slots[fed] + 1] == toks[fed]).all()
     assert sorted(int(t) for r in rows for t in r[2]) == sorted(
         t for r in sample for t in r["tokens"])
-    ref = Reference(smoke_cfg, 7)
+    ref = dense.Reference(smoke_cfg, 7)
     h_packed, t_packed = correctness._served_hidden(ref, rows)
     alone = [correctness.served_rows([sample[i]], 128) for i in (1, 3, 2, 0)]
     h_alone = np.concatenate([np.asarray(correctness._served_hidden(

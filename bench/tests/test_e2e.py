@@ -59,13 +59,13 @@ def test_counts_in_flight_requests_are_not_done():
 
 
 def test_closed_loop_first_round_is_spread_and_the_window_follows_the_ramp(
-        smoke_cfg):
+        smoke_cfg, dense):
     """Client ``i`` of ``n`` first asks for ``(i + 1/2) / n`` of its
     output length; the window opens ``ramp_s`` after the first
     submissions."""
     from bench import serving, traffic as traffic_mod
     t = dict(fixture_json("smoke-closed.json"), ramp_s=1.0)
-    eng = serving.build_engine(smoke_cfg, t, 9)
+    eng = serving.build_engine(dense, smoke_cfg, t, 9)
     serving.warm_up(eng, smoke_cfg, t, smoke_cfg["vocab_size"])
     win = serving.run_window(eng, smoke_cfg, t, 9, 1.0)
     assert win["compiles_in_window"] == 0 and win["traces_in_window"] == 0
@@ -80,12 +80,12 @@ def test_closed_loop_first_round_is_spread_and_the_window_follows_the_ramp(
 
 
 def test_drain_serves_past_the_window_until_every_prefill_streamed(
-        smoke_cfg):
+        smoke_cfg, dense):
     """With ``drain_s`` the session goes on past the window's close,
     sending nothing new, until every prompt sent has its first token."""
     from bench import serving
     t = dict(fixture_json("smoke-closed.json"), drain_s=30.0)
-    eng = serving.build_engine(smoke_cfg, t, 11)
+    eng = serving.build_engine(dense, smoke_cfg, t, 11)
     serving.warm_up(eng, smoke_cfg, t, smoke_cfg["vocab_size"])
     win = serving.run_window(eng, smoke_cfg, t, 11, 1.0)
     assert win["compiles_in_window"] == 0

@@ -160,7 +160,7 @@ def test_program_spans_on_a_cpu_trace_agree_with_step_entries(tmp_path):
     from bench.tests.smoke import smoke_cell
     cell = smoke_cell("smoke-closed.json")
     cfg, traffic, seed = cell["config"], cell["traffic"], 2 ** 33 + 5
-    eng = serving.build_engine(cfg, traffic, seed)
+    eng = serving.build_engine(cell["arch"], cfg, traffic, seed)
     serving.warm_up(eng, cfg, traffic, cfg["vocab_size"])
     win = serving.run_window(eng, cfg, traffic, seed, 2.0, tmp_path)
     red = sp.reduce_spans(trace_reduce.load(tmp_path).planes)

@@ -1,12 +1,15 @@
 """Weights and quantization policy from the seed, on the benchmark's side.
 
 Every weight is drawn from its own key, ``fold_in(fold_in(seed key,
-role), layer)``, so the plain reference can make any one layer again
-without the rest and without anything the program made.  The program's
-tree is built by stacking those layers, in bf16, on the device, in one
-jitted call that also packs it with the program's
-``apply_policy_packed``; the compile cache serves that call from the
-second run on.
+role id), layer)``, so the plain reference can make any one layer again
+without the rest and without anything the program made.  The embedding
+and the unembed have the ids 1 and 2; each architecture numbers its own
+roles after them (``role_ids``).  The program's tree is built by
+stacking those layers, in bf16, on the device, in one jitted call that
+also packs it with the program's ``apply_policy_packed``; the compile
+cache serves that call from the second run on.  What the tree holds is
+the architecture's (``arch/<architecture>.py`` ``program_tree``); this
+module keeps what every architecture shares.
 
 The kernel-wise policy is drawn from the configuration's fixed
 ``policy_seed``: it sets the packed shapes and so the compiled programs
@@ -16,27 +19,22 @@ from __future__ import annotations
 
 import numpy as np
 
-ROLES = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
-ROLE_IDS = {"embed": 1, "unembed": 2, **{r: 3 + i for i, r in
-                                         enumerate(ROLES)}}
+EMBED_ID, UNEMBED_ID = 1, 2
+
+
+def role_ids(roles) -> dict:
+    """Key ids of an architecture's matmul roles, after the embedding's
+    and the unembed's, in the order given."""
+    return {r: 3 + i for i, r in enumerate(roles)}
 
 
 def dims(cfg: dict) -> dict:
-    d = cfg["hidden_size"]
-    hd = cfg["head_dim"]
-    return {"d": d, "hq": cfg["num_attention_heads"],
-            "hkv": cfg["num_key_value_heads"], "hd": hd,
-            "ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"],
+    """The sizes every decoder configuration states."""
+    return {"d": cfg["hidden_size"], "hq": cfg["num_attention_heads"],
+            "hkv": cfg["num_key_value_heads"], "hd": cfg["head_dim"],
+            "vocab": cfg["vocab_size"],
             "vocab_padded": -(-cfg["vocab_size"] // 128) * 128,
             "layers": cfg["num_hidden_layers"]}
-
-
-def layer_shapes(cfg: dict) -> dict:
-    """(K, N) of each matmul weight of one layer: inputs on K."""
-    m = dims(cfg)
-    d, q, kv, ff = m["d"], m["hq"] * m["hd"], m["hkv"] * m["hd"], m["ff"]
-    return {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d),
-            "wg": (d, ff), "wu": (d, ff), "wd": (ff, d)}
 
 
 def base_key(seed: int):
@@ -98,58 +96,61 @@ def untie(w, bits, amax):
     return jnp.where(tie, down, w)
 
 
-def layer_weight(key, role: str, layer, shape):
-    """One layer's bf16 ``role`` weight (``layer`` may be traced)."""
+def layer_weight(key, role_id: int, layer, shape):
+    """One layer's bf16 weight of the role with key id ``role_id``
+    (``layer`` may be traced)."""
     import jax
-    k = jax.random.fold_in(jax.random.fold_in(key, ROLE_IDS[role]), layer)
+    k = jax.random.fold_in(jax.random.fold_in(key, role_id), layer)
     return _normal(k, shape, shape[0])
+
+
+def stack(key, role_id: int, layers: int, shape, group_bits):
+    """``layers`` weights of one role stacked (layer, K, N), ties removed
+    under its per-group QBNs and the stack's channel maxima."""
+    import jax
+    import jax.numpy as jnp
+    w = jax.vmap(lambda r: layer_weight(key, role_id, r, shape))(
+        jnp.arange(layers))
+    return untie(w, channel_bits(group_bits, shape[1]), channel_amax(w))
 
 
 def embed(key, vocab_padded: int, d: int):
     """(vocab_padded, d) bf16 token embedding."""
     import jax
-    return _normal(jax.random.fold_in(key, ROLE_IDS["embed"]),
+    return _normal(jax.random.fold_in(key, EMBED_ID),
                    (vocab_padded, d), d)
 
 
 def unembed(key, d: int, vocab_padded: int):
     """(d, vocab_padded) bf16 output projection."""
     import jax
-    return _normal(jax.random.fold_in(key, ROLE_IDS["unembed"]),
+    return _normal(jax.random.fold_in(key, UNEMBED_ID),
                    (d, vocab_padded), d)
 
 
-def program_tree(key, cfg: dict) -> dict:
-    """The program's parameter tree (``LM.init`` layout, one ``attn``
-    block per pattern period) in bf16, ties removed under the policy;
-    norm weights 0, i.e. gain 1 under the program's ``(1 + w)`` RMSNorm."""
-    import jax
+def tree(key, cfg: dict, blocks: tuple, unembed_bits) -> dict:
+    """The program's parameter tree (``LM.init`` layout) around an
+    architecture's ``blocks``, one stacked block per pattern position:
+    final norm 0 (gain 1 under the program's ``(1 + w)`` RMSNorm), the
+    unembed with ties removed under its QBNs, the embedding."""
     import jax.numpy as jnp
     m = dims(cfg)
-    R = m["layers"]
-    bits = policy_bits(cfg)
-    block = {"norm": jnp.zeros((R, m["d"]), jnp.bfloat16),
-             "ffn_norm": jnp.zeros((R, m["d"]), jnp.bfloat16)}
-    for role, shape in layer_shapes(cfg).items():
-        w = jax.vmap(lambda r, role=role, shape=shape: layer_weight(
-            key, role, r, shape))(jnp.arange(R))
-        block[role] = untie(w, channel_bits(bits[role], shape[1]),
-                            channel_amax(w))
     u = unembed(key, m["d"], m["vocab_padded"])
-    return {"blocks": (block,),
+    return {"blocks": blocks,
             "final_norm": jnp.zeros((m["d"],), jnp.bfloat16),
-            "unembed": untie(u, channel_bits(bits["unembed"], u.shape[1]),
+            "unembed": untie(u, channel_bits(unembed_bits, u.shape[1]),
                              channel_amax(u)),
             "embed": embed(key, m["vocab_padded"], m["d"])}
 
 
-def policy_bits(cfg: dict) -> dict:
-    """Per-group weight QBNs of each matmul role (and the unembed), drawn
-    from the configuration's ``policy_seed``; groups are
-    ``ceil(N / n_groups)`` contiguous output channels."""
+def policy_bits(cfg: dict, layer_shapes: dict) -> dict:
+    """Per-group weight QBNs of each matmul role of ``layer_shapes``
+    ({role: (K, N)}, in the architecture's order) and of the unembed,
+    drawn in that order from the configuration's ``policy_seed``; groups
+    are ``ceil(N / n_groups)`` contiguous output channels."""
     pol = cfg["policy"]
     rng = np.random.default_rng(pol["policy_seed"])
-    shapes = dict(layer_shapes(cfg))
+    shapes = dict(layer_shapes)
     shapes["unembed"] = (dims(cfg)["d"], dims(cfg)["vocab_padded"])
     return {role: rng.choice(np.asarray(pol["weight_bits"], np.float64),
                              min(pol["max_groups"], n))
